@@ -39,6 +39,8 @@ def main() -> None:
         for name, _ in ALL:
             print(name)
         return
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = []
     for name, module in ALL:

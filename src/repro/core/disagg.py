@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
-from jax.experimental.shard_map import shard_map
 
 from repro.models import model as M
 from repro.models import sharding as S
@@ -47,10 +46,10 @@ def kv_handoff(cache, mesh: Mesh, batch_axes=("data",)):
 
     flat, treedef = jax.tree_util.tree_flatten(cache)
     flat_specs = treedef.flatten_up_to(specs)
-    out = shard_map(body, mesh=mesh,
-                    in_specs=tuple(flat_specs),
-                    out_specs=tuple(flat_specs),
-                    check_rep=False)(*flat)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=tuple(flat_specs),
+                        out_specs=tuple(flat_specs),
+                        check_vma=False)(*flat)
     return jax.tree_util.tree_unflatten(treedef, list(out))
 
 

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode;
-on a real TPU backend they compile to Mosaic.  The engines call these —
-never ``pallas_call`` directly.
+On the CPU backend (the test suite) the kernels execute in
+``interpret=True`` mode; on a TPU they compile to Mosaic.  Any other
+backend is an error, never a silent fall back to interpretation.  The
+engines call these — never ``pallas_call`` directly.
 """
 from __future__ import annotations
 
@@ -21,7 +22,12 @@ from repro.kernels.paged_prefill_attention import paged_prefill_attention
 
 @functools.cache
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for TPU or run interpreted on the "
+            f"CPU; JAX backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 def prefill_attention(q, k_cache, v_cache, kv_len, q_offset, *,

@@ -207,6 +207,9 @@ def main():
                     help="avg time-between-tokens SLO target in seconds")
     args = ap.parse_args()
 
+    if args.wall_clock or args.real:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     if args.wall_clock:
         _run_wall_clock(args)
         return

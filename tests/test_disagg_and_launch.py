@@ -56,7 +56,10 @@ def test_kv_handoff_moves_cache_pod0_to_pod1():
         print(json.dumps({"pod0": per_pod[0], "pod1": per_pod[1],
                           "orig_nonzero": orig > 0}))
     """)
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # pinned to the CPU: the child must never reach for an accelerator
+    # that this (or any other) process may hold
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -163,3 +166,32 @@ def test_dryrun_results_cover_all_40_pairs():
     skips = [r for r in recs if r.get("status") == "skipped"]
     assert {(r["arch"], r["shape"]) for r in skips} == {
         ("whisper_tiny", "long_500k")}
+
+
+# ---------------------------------------------------------------------------
+# launch/compile_cache: where the persistent compilation cache lives
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    fixed, git-ignored directory at the root of the checkout."""
+    import jax
+
+    from repro.launch import compile_cache
+    updates = []
+    # record instead of turning the cache on: tests never enable it
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    got = compile_cache.enable_compile_cache()
+    if env_dir is None:
+        want = os.path.join(REPO, ".jax_cache")
+        assert got == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+        with open(os.path.join(REPO, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    else:
+        assert got == env_dir and updates == []

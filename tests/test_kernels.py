@@ -253,3 +253,20 @@ def test_kernel_matches_model_flash_attention():
         q, k, v, jnp.array([sq] * b, jnp.int32), jnp.array([0], jnp.int32),
         block_q=32, block_kv=32)
     assert float(jnp.abs(out_model - out_kernel).max()) < 2e-5
+
+
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Kernels run interpreted on the CPU and compile on a TPU; any other
+    backend raises instead of silently interpreting."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    ops._interpret.cache_clear()
+    try:
+        if interpret is None:
+            with pytest.raises(RuntimeError, match="gpu"):
+                ops._interpret()
+        else:
+            assert ops._interpret() is interpret
+    finally:
+        ops._interpret.cache_clear()
