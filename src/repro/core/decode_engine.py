@@ -91,6 +91,10 @@ class DecodeEngine:
         # (rid, token) pairs emitted by the LAST step() — the streaming
         # feed the serving Cluster forwards to request handles
         self.stream_events: List[Tuple[str, int]] = []
+        #: step-phase recorder (repro.obs.tracer.PhaseRecorder), set by
+        #: the wall-clock runtime when it has a tracer; None costs one
+        #: check per phase boundary
+        self.phases = None
 
         if self.backend == "paged":
             # the allocator's block tables ARE the physical mapping
@@ -169,6 +173,9 @@ class DecodeEngine:
         return None
 
     def admit(self, now: float) -> List[Request]:
+        ph = self.phases
+        if ph is not None:
+            ph.open("decode_admit")
         admitted = self.scheduler.admit()
         pages: List[int] = []
         payload_k, payload_v = [], []
@@ -242,6 +249,11 @@ class DecodeEngine:
                 req.t_finish = now
                 self.scheduler.finish(req.rid)
                 del self.slots[s]
+        if ph is not None:
+            if admitted:
+                ph.close(admitted=len(admitted), pages=len(pages))
+            else:
+                ph.drop()
         return admitted
 
     def step(self, now: float) -> List[FinishedRequest]:
@@ -254,6 +266,9 @@ class DecodeEngine:
             nxt = self._iteration_paged()
         else:
             nxt = self._iteration_dense()
+        ph = self.phases
+        if ph is not None:
+            ph.open("decode_commit")
         finished: List[FinishedRequest] = []
         for s in list(self.slots):
             st = self.slots[s]
@@ -275,6 +290,8 @@ class DecodeEngine:
                 self.scheduler.finish(req.rid)
                 finished.append(FinishedRequest(req=req, tokens=st.tokens))
                 del self.slots[s]
+        if ph is not None:
+            ph.close()
         return finished
 
     def cancel(self, rid: str) -> bool:
@@ -291,6 +308,9 @@ class DecodeEngine:
 
     def _iteration_paged(self) -> np.ndarray:
         """Full-slot-batch fused decode against the page pool."""
+        ph = self.phases
+        if ph is not None:
+            ph.open("decode_build")
         ms, ps, trash = self.max_slots, self.page_size, self._trash
         toks = np.zeros((ms, 1), np.int32)
         pos = np.zeros((ms,), np.int32)
@@ -346,6 +366,9 @@ class DecodeEngine:
         else:
             extra = ()
             fn = self._decode_paged
+        if ph is not None:
+            ph.close(slots=len(self.slots))
+            ph.open("decode_device")
         if cross:
             nxt, kp, vp = fn(
                 self.params, jnp.asarray(toks), jnp.asarray(pos),
@@ -358,7 +381,10 @@ class DecodeEngine:
                 jnp.asarray(pages), jnp.asarray(offs), jnp.asarray(bt),
                 jnp.asarray(lens), *extra, self.pool.k, self.pool.v)
         self.pool = PagePool(k=kp, v=vp)
-        return np.asarray(nxt)
+        nxt = np.asarray(nxt)
+        if ph is not None:
+            ph.close()
+        return nxt
 
     def _iteration_dense(self) -> np.ndarray:
         toks = np.zeros((self.max_slots, 1), np.int32)

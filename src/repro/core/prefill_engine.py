@@ -76,6 +76,20 @@ class PrefilledKV:
     cached_tokens: int = 0
     cross_cached: bool = False
 
+    @property
+    def n_pages(self) -> int:
+        """Pages the payload carries, self and cross (0 for dense)."""
+        return sum(a.shape[1] for a in (self.pages_k, self.cross_k)
+                   if a is not None)
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the payload's arrays, from their shapes and dtypes
+        (no device sync)."""
+        arrays = [self.pages_k, self.pages_v, self.cross_k, self.cross_v]
+        return sum(a.nbytes for a in arrays + jax.tree_util.tree_leaves(
+            self.cache) if a is not None)
+
 
 def _pow2(n: int) -> int:
     return 1 << max(0, n - 1).bit_length()
@@ -136,6 +150,10 @@ class PrefillEngine:
         self.prefix_cache = (prefix_cache and self.backend == "paged"
                              and not cfg.sliding_window)
         self._page_keys: Dict[str, List[bytes]] = {}
+        #: step-phase recorder (repro.obs.tracer.PhaseRecorder), set by
+        #: the wall-clock runtime when it has a tracer; None costs one
+        #: check per phase boundary
+        self.phases = None
 
         if self.backend == "paged":
             self.alloc = PagedAllocator(
@@ -302,14 +320,21 @@ class PrefillEngine:
     def step(self, now: float) -> List[PrefilledKV]:
         """Run ONE fixed-size chunk (the paper's prefill iteration unit).
         Returns requests whose prefill completed this step."""
+        ph = self.phases
+        if ph is not None:
+            ph.open("prefill_build")     # closed before the dispatch
         if not self._chunk_queue:
             self._refill_chunks()
         if not self._chunk_queue:
+            if ph is not None:
+                ph.close()
             return []
         chunk = self._chunk_queue.popleft()
         self.chunk_steps += 1
         if self.backend == "paged":
             return self._step_paged(chunk, now)
+        if ph is not None:
+            ph.close()
         return self._step_dense(chunk, now)
 
     # -- paged backend -------------------------------------------------
@@ -373,6 +398,10 @@ class PrefillEngine:
                     epos = np.arange(self.enc_ctx)
                     cpg[i] = ctab[epos // ps]
                     scattered.append(seg.rid)
+        ph = self.phases
+        if ph is not None:
+            ph.close()
+            ph.open("prefill_device")
         if cross and scattered:
             next_tok, _, kp, vp = self._prefill_paged(
                 self.params, jnp.asarray(toks), jnp.asarray(qoff),
@@ -401,6 +430,10 @@ class PrefillEngine:
             # later requests with the same encoder input alias them
             self.alloc.commit_cross(rid)
         next_tok = np.asarray(next_tok)
+        if ph is not None:
+            ph.close(segs=[[s.rid, s.req_start, s.length] for s in segs],
+                     rows=ns, cols=sq)
+            ph.open("prefill_finish")
         finished: List[PrefilledKV] = []
         for i, seg in enumerate(segs):
             req = self._reqs[seg.rid]
@@ -411,6 +444,8 @@ class PrefillEngine:
             if req.prefilled >= req.prompt_len:
                 finished.append(
                     self._finish_paged(req, int(next_tok[i]), now))
+        if ph is not None:
+            ph.close(pages=sum(pk.n_pages for pk in finished))
         return finished
 
     def _finish_paged(self, req: Request, first_tok: int, now: float

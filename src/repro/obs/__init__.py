@@ -16,21 +16,23 @@ and benchmarked when on (the ``obs_overhead`` scenario in
   * ``SLOSpec``          — DistServe-style TTFT/TBT attainment targets
     threaded through ``summarize()`` and ``FleetReport`` (goodput).
   * ``EventLoopProfiler`` — per-event-kind handler profiler (promoted
-    from ``repro.fleet.profile``; hangs off ``Cluster.profiler`` and
-    ``AsyncCluster.profiler``).
+    from ``repro.fleet.profile``; hangs off ``Cluster.profiler``).
+  * ``PhaseRecorder``    — step-phase spans of the wall-clock runtime's
+    workers, mirrored into the JAX profiler as ``TraceAnnotation``s.
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                observe_request)
 from repro.obs.profile import EventLoopProfiler
 from repro.obs.slo import SLOSpec, attainment, good_count, meets_slo
-from repro.obs.tracer import (SCHEMA_VERSION, TERMINAL_EVENTS, Tracer,
-                              read_jsonl, validate_chains,
-                              validate_jsonl_records, validate_perfetto)
+from repro.obs.tracer import (SCHEMA_VERSION, TERMINAL_EVENTS,
+                              PhaseRecorder, Tracer, read_jsonl,
+                              validate_chains, validate_jsonl_records,
+                              validate_perfetto)
 
 __all__ = [
     "Counter", "EventLoopProfiler", "Gauge", "Histogram",
-    "MetricsRegistry", "SCHEMA_VERSION", "SLOSpec", "TERMINAL_EVENTS",
-    "Tracer", "attainment", "good_count", "meets_slo", "observe_request",
-    "read_jsonl", "validate_chains", "validate_jsonl_records",
-    "validate_perfetto",
+    "MetricsRegistry", "PhaseRecorder", "SCHEMA_VERSION", "SLOSpec",
+    "TERMINAL_EVENTS", "Tracer", "attainment", "good_count", "meets_slo",
+    "observe_request", "read_jsonl", "validate_chains",
+    "validate_jsonl_records", "validate_perfetto",
 ]

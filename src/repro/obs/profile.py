@@ -2,39 +2,27 @@
 ``repro.fleet.profile``, which re-exports it for compatibility).
 
 Assign an instance to ``Cluster.profiler`` (the event loop calls
-``record(kind, dt)`` around each dispatched event) or to
-``AsyncCluster.profiler`` (each worker records its step kinds:
-``prefill_step`` / ``decode_step`` / ``transfer``) and read
+``record(kind, dt)`` around each dispatched event) and read
 ``report()`` after the run.  Overhead is two ``perf_counter`` calls
 per event (~100ns), so profiling a million-event run costs well under
 a second — cheap enough for the ``--profile`` flag to be usable on
-full fleet scenarios.
-
-The wall-clock runtime's workers record concurrently: construct with
-``thread_safe=True`` there (a lock per record); the single-threaded
-event loop keeps the lock-free default.
+full fleet scenarios.  The wall-clock ``AsyncCluster`` times its steps
+with its tracer's phase spans instead (docs/observability.md).
 """
 from __future__ import annotations
 
-import threading
 from collections import defaultdict
 from typing import Dict, Optional
 
 
 class EventLoopProfiler:
-    def __init__(self, thread_safe: bool = False) -> None:
+    def __init__(self) -> None:
         self.counts: Dict[str, int] = defaultdict(int)
         self.time_s: Dict[str, float] = defaultdict(float)
-        self._lock = threading.Lock() if thread_safe else None
 
     def record(self, kind: str, dt: float) -> None:
-        if self._lock is None:
-            self.counts[kind] += 1
-            self.time_s[kind] += dt
-        else:
-            with self._lock:
-                self.counts[kind] += 1
-                self.time_s[kind] += dt
+        self.counts[kind] += 1
+        self.time_s[kind] += dt
 
     @property
     def total_events(self) -> int:

@@ -24,6 +24,14 @@ atomic under the CPython GIL — so ``AsyncCluster`` workers share one
 tracer with no lock on the hot path ("lock-free append").  Export
 happens after (or outside) the run.
 
+Step phases: a ``PhaseRecorder`` (one per ``AsyncCluster`` worker)
+splits a worker's step span into its phases (lock wait, host build,
+device, finish) and opens each one also as a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+holds the phases on the device trace's clock.  The engines reach it
+through their ``phases`` attribute, ``None`` unless a tracer is
+attached.
+
 Perfetto export maps the records onto the Chrome ``trace_event``
 format (https://ui.perfetto.dev loads the file directly):
 
@@ -172,6 +180,58 @@ class Tracer:
     def write_perfetto(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_perfetto(), f)
+
+
+class PhaseRecorder:
+    """Phase spans of one worker's steps, mirrored into the JAX profiler.
+
+    ``open(name)`` starts a phase, as a ``jax.profiler.TraceAnnotation``
+    of the same name and on ``clock``; ``close(**args)`` ends it and
+    holds it as a child of the step in progress; ``drop()`` ends it and
+    records nothing.  Phases of one recorder follow each other and do
+    not nest.  ``end_step(name, ts)`` emits the step's parent span, from
+    ``ts`` to now, and then its children, all on ``track`` and with the
+    same ``step`` number (counted per recorder); ``end_step(name, ts,
+    ran=False)`` forgets the children of a step that did no work.  One
+    thread owns a recorder.  The annotation of a dropped phase, or of a
+    forgotten step's phases, stays in the profile.
+    """
+
+    def __init__(self, tracer: Tracer, clock, track: str):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self.tracer = tracer
+        self.clock = clock
+        self.track = track
+        self.steps = 0
+        self._open = None              # (name, ts, annotation)
+        self._children: List[tuple] = []
+
+    def open(self, name: str) -> None:
+        ann = self._annotation(name)
+        ann.__enter__()
+        self._open = (name, self.clock(), ann)
+
+    def close(self, **args) -> None:
+        name, ts, ann = self._open
+        dur = self.clock() - ts
+        ann.__exit__(None, None, None)
+        self._open = None
+        self._children.append((name, ts, dur, args))
+
+    def drop(self) -> None:
+        self._open[2].__exit__(None, None, None)
+        self._open = None
+
+    def end_step(self, name: str, ts: float, ran: bool = True) -> None:
+        children, self._children = self._children, []
+        if not ran:
+            return
+        step, self.steps = self.steps, self.steps + 1
+        tr, track = self.tracer, self.track
+        tr.span(name, track, ts, self.clock() - ts, step=step)
+        for child, cts, dur, args in children:
+            tr.span(child, track, cts, dur, step=step, **args)
 
 
 # -- readers / validators (tools/check_trace.py + tests) ----------------

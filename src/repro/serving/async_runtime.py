@@ -62,7 +62,7 @@ from repro.core.sched.dispatcher import DecodeLoad, Dispatcher
 from repro.core.sched.flip import Role
 from repro.core.sched.global_scheduler import GlobalScheduler
 from repro.obs.metrics import MetricsRegistry, observe_request
-from repro.obs.tracer import Tracer
+from repro.obs.tracer import PhaseRecorder, Tracer
 from repro.runtime.request import (TERMINAL_PHASES, Phase, Request,
                                    SamplingParams, summarize)
 from repro.serving.cluster import RequestResult, SimResult
@@ -227,7 +227,9 @@ class AsyncCluster:
                 decode_policy=decode_policy, max_slots=max_batch,
                 n_pages=n_pages, page_size=page_size, max_seq=max_seq,
                 backend=backend, step_dt=step_dt,
-                prefix_cache=prefix_cache)
+                prefix_cache=prefix_cache,
+                phases=None if tracer is None
+                else PhaseRecorder(tracer, self.now, f"i{i}"))
 
         self.instances: List[InstanceRuntime] = \
             [mk(i, Role.PREFILL) for i in range(n_prefill)] \
@@ -272,10 +274,6 @@ class AsyncCluster:
             "bytes_sent": self.network.bytes_sent,
             "bytes_saved": self.network.bytes_saved,
             "retransmits": self.network.retransmits})
-        #: optional per-step-kind instrumentation (repro.obs.profile.
-        #: EventLoopProfiler — construct with thread_safe=True here):
-        #: workers call record("prefill_step"/"decode_step", dt)
-        self.profiler = None
 
         # workers are created here, started lazily on first submit()
         self._wake: Dict[str, threading.Event] = \
@@ -349,6 +347,8 @@ class AsyncCluster:
         stamped with the wall clock at the moment of submission (an
         open-loop client controls pacing, not timestamps)."""
         self.start()
+        tr = self.tracer
+        t_enter = self.now() if tr is not None else 0.0
         if request is None:
             assert prompt_tokens is not None, \
                 "submit() needs prompt_tokens or a Request"
@@ -375,6 +375,9 @@ class AsyncCluster:
             if self._collect_tokens:
                 self._buffers[request.rid] = []
         self._route_prefill(request)
+        if tr is not None:
+            tr.span("submit", "cluster", t_enter, self.now() - t_enter,
+                    rid=request.rid)
         return AsyncRequestHandle(self, request)
 
     def cancel(self, rid: str) -> bool:
@@ -587,22 +590,22 @@ class AsyncCluster:
 
     def _prefill_loop(self, p: InstanceRuntime) -> None:
         wake, xfer = self._wake[p.iid], self._xfer.get(p.iid)
+        ph = p.phases                 # None unless a tracer is attached
         while not self._stop.is_set():
             if p.iid in self._dead:
                 return
             if self._paused(p.iid):
                 continue
-            obs = self.tracer is not None or self.profiler is not None
-            t0 = self.now() if obs else 0.0
+            if ph is not None:
+                t0 = self.now()
+                ph.open("prefill_lock")
             with p.lock:
+                if ph is not None:
+                    ph.close()
                 ran = p.prefill_start(self.now()) is not None
                 outcomes = p.prefill_complete(self.now()) if ran else []
-            if obs and ran:
-                dt = self.now() - t0
-                if self.tracer is not None:
-                    self.tracer.span("prefill_chunk", p.iid, t0, dt)
-                if self.profiler is not None:
-                    self.profiler.record("prefill_step", dt)
+            if ph is not None:
+                ph.end_step("prefill_chunk", t0, ran)
             if p.iid in self._dead:
                 return        # crashed mid-step: completions are lost
             for oc in outcomes:
@@ -644,22 +647,22 @@ class AsyncCluster:
 
     def _decode_loop(self, d: InstanceRuntime) -> None:
         wake = self._wake[d.iid]
+        ph = d.phases                 # None unless a tracer is attached
         while not self._stop.is_set():
             if d.iid in self._dead:
                 return
             if self._paused(d.iid):
                 continue
-            obs = self.tracer is not None or self.profiler is not None
-            t0 = self.now() if obs else 0.0
+            if ph is not None:
+                t0 = self.now()
+                ph.open("decode_lock")
             with d.lock:
+                if ph is not None:
+                    ph.close()
                 ran = d.decode_start(self.now()) is not None
                 ev = d.decode_complete(self.now()) if ran else None
-            if obs and ran:
-                dt = self.now() - t0
-                if self.tracer is not None:
-                    self.tracer.span("decode_step", d.iid, t0, dt)
-                if self.profiler is not None:
-                    self.profiler.record("decode_step", dt)
+            if ph is not None:
+                ph.end_step("decode_step", t0, ran)
             if d.iid in self._dead:
                 return        # crashed mid-step: completions are lost
             if ev is not None:
@@ -756,7 +759,9 @@ class AsyncCluster:
                 if self.tracer is not None:
                     self.tracer.span("transfer", did, t_start,
                                      max(0.0, self.now() - t_start),
-                                     rid=req.rid, attempt=attempt)
+                                     rid=req.rid, attempt=attempt,
+                                     pages=oc.payload.n_pages,
+                                     bytes=oc.payload.nbytes)
                 if self.metrics.enabled:
                     self.metrics.counter("kv_transfers").inc()
                 self._wake[did].set()

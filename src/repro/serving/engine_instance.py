@@ -34,7 +34,8 @@ class EngineInstance:
                  prefill_policy="sjf", sched_batch=16, chunk_size=16,
                  decode_policy="reserve-dynamic", max_slots=8,
                  n_pages=256, page_size=16, max_seq=128,
-                 backend="auto", step_dt=0.01, prefix_cache=False):
+                 backend="auto", step_dt=0.01, prefix_cache=False,
+                 phases=None):
         self.iid = iid
         self.flip = FlipMachine(role)
         self.step_dt = step_dt
@@ -60,6 +61,9 @@ class EngineInstance:
             max_seq=max_seq, policy=decode_policy, n_pages=n_pages,
             page_size=page_size, backend=backend,
             prefix_cache=prefix_cache)
+        # step-phase recorder (repro.obs.tracer.PhaseRecorder) shared by
+        # both engines: one worker thread drives an instance
+        self.phases = self.pe.phases = self.de.phases = phases
 
     # -- prefill facet ------------------------------------------------------
     def prefill_enqueue(self, req: Request) -> None:

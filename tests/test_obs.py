@@ -303,7 +303,7 @@ def test_stall_snapshot_is_the_registry_probe(opt13b):
 def test_profiler_promotion_compat():
     from repro.fleet.profile import EventLoopProfiler as OldName
     assert OldName is EventLoopProfiler
-    p = EventLoopProfiler(thread_safe=True)
+    p = EventLoopProfiler()
     p.record("decode_step", 0.5)
     p.record("decode_step", 1.5)
     rep = p.report(wall_s=4.0)
@@ -357,3 +357,161 @@ def test_async_chaos_tracer_exactly_one_terminal():
     assert terminal == len(reqs)
     # the exported document is loadable and valid
     assert validate_perfetto(tracer.to_perfetto()) == []
+
+
+# -- step phases of the wall-clock runtime -----------------------------------
+STEP_PHASES = {"prefill_chunk": ("prefill_lock", "prefill_build",
+                                 "prefill_device", "prefill_finish"),
+               "decode_step": ("decode_lock", "decode_admit",
+                               "decode_build", "decode_device",
+                               "decode_commit")}
+
+
+def _smoke_model():
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_smoke_config
+    from repro.models import model as M
+    cfg = dataclasses.replace(get_smoke_config("qwen2_0_5b"),
+                              dtype="float32")
+    return cfg, M.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _smoke_async_run(tracer, n=6):
+    """A 1P+1D ``AsyncCluster`` at smoke size; returns the requests and
+    every engine of its instances."""
+    from repro.serving import AsyncCluster
+    cfg, params = _smoke_model()
+    reqs = generate("Mixed", n, seed=4, max_prompt=48, max_decode=8,
+                    vocab_size=1000)
+    with AsyncCluster(cfg, params=params, chunk_size=16, max_seq=128,
+                      max_batch=8, n_pages=256, tracer=tracer) as ac:
+        engines = [e for i in ac.instances for e in (i.pe, i.de)]
+        for r in copy.deepcopy(reqs):
+            ac.submit(request=r)
+        assert ac.drain(timeout=240), "run wedged"
+    return reqs, engines
+
+
+@pytest.fixture(scope="module")
+def traced_async_run():
+    tracer = Tracer(clock="wall")
+    reqs, engines = _smoke_async_run(tracer)
+    return reqs, engines, tracer
+
+
+def test_async_step_phases_nest_in_their_steps(traced_async_run):
+    """Every step span has its phase children (same track and ``step``),
+    inside it, in order, and not overlapping; the step numbers of a
+    worker count up from 0."""
+    _, _, tracer = traced_async_run
+    spans = [e for e in tracer.events if e["type"] == "span"]
+    parents = {(s["track"], s["args"]["step"]): s for s in spans
+               if s["name"] in STEP_PHASES}
+    kids = {}
+    for s in spans:
+        if any(s["name"] in v for v in STEP_PHASES.values()):
+            kids.setdefault((s["track"], s["args"]["step"]), []).append(s)
+    assert set(kids) == set(parents)
+    assert {p["name"] for p in parents.values()} == set(STEP_PHASES)
+    for track in {t for t, _ in parents}:
+        steps = sorted(k for t, k in parents if t == track)
+        assert steps == list(range(len(steps)))
+    eps = 1e-9
+    for key, par in parents.items():
+        ch = kids[key]
+        names = [c["name"] for c in ch]
+        order = STEP_PHASES[par["name"]]
+        want = [n for n in order if n in names]
+        assert names == want, (key, names)
+        required = [n for n in order if n != "decode_admit"]
+        assert set(required) <= set(names), (key, names)
+        end = par["ts"] + par["dur"]
+        for c in ch:
+            assert par["ts"] - eps <= c["ts"]
+            assert c["ts"] + c["dur"] <= end + eps
+        for a, b in zip(ch, ch[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + eps
+        for c in ch:
+            if c["name"] == "decode_admit":
+                assert c["args"]["admitted"] >= 1
+                assert c["args"]["pages"] >= 1
+            if c["name"] == "decode_build":
+                assert 1 <= c["args"]["slots"] <= 8
+
+
+def test_async_prefill_segments_tile_each_prompt(traced_async_run):
+    """The ``segs`` of the ``prefill_device`` spans cover each finished
+    prompt exactly once, in order, inside the padded (rows, cols)."""
+    reqs, _, tracer = traced_async_run
+    got = {}
+    for s in tracer.events:
+        if s["type"] == "span" and s["name"] == "prefill_device":
+            segs = s["args"]["segs"]
+            assert len(segs) <= s["args"]["rows"]
+            for rid, start, n in segs:
+                assert 1 <= n <= s["args"]["cols"]
+                got.setdefault(rid, []).append((start, n))
+    assert set(got) == {r.rid for r in reqs}
+    for r in reqs:
+        pos = 0
+        for start, n in sorted(got[r.rid]):
+            assert start == pos
+            pos += n
+        assert pos == r.prompt_len
+    pages = sum(s["args"]["pages"] for s in tracer.events
+                if s["type"] == "span" and s["name"] == "prefill_finish")
+    assert pages == sum(-(-r.prompt_len // 16) for r in reqs)
+
+
+def test_async_submit_and_transfer_spans(traced_async_run):
+    """One ``submit`` span per request on the cluster track; each
+    ``transfer`` carries its payload's pages and bytes; the records
+    still pass every validator."""
+    reqs, _, tracer = traced_async_run
+    subs = [e for e in tracer.events if e["name"] == "submit"]
+    assert sorted(e["rid"] for e in subs) == sorted(r.rid for r in reqs)
+    assert all(e["track"] == "cluster" and e["dur"] >= 0 for e in subs)
+    # K and V x smoke qwen2's 2 layers x 16 tokens x 2 kv heads x head
+    # dim 64 x 4 B (float32), per page
+    xfer = [e for e in tracer.events if e["name"] == "transfer"]
+    assert len(xfer) == len(reqs)
+    by_rid = {r.rid: r for r in reqs}
+    for e in xfer:
+        n = -(-by_rid[e["rid"]].prompt_len // 16)
+        assert e["args"]["pages"] == n
+        assert e["args"]["bytes"] == n * 2 * 2 * 16 * 2 * 64 * 4
+    records = tracer.to_jsonl_records()
+    assert validate_jsonl_records(records) == []
+    assert validate_chains(records) == []
+    assert validate_perfetto(tracer.to_perfetto()) == []
+
+
+def test_untraced_runtimes_emit_no_phases(monkeypatch):
+    """With no tracer the engines' phase hook is None and nothing calls
+    into a recorder or the profiler; the synchronous ``Cluster``, traced,
+    emits none of the wall-clock runtime's phase or submit spans."""
+    import jax
+
+    from repro.obs.tracer import PhaseRecorder
+
+    def boom(*a, **k):
+        raise AssertionError("phase recorder used with tracing off")
+    for name in ("__init__", "open", "close", "drop", "end_step"):
+        monkeypatch.setattr(PhaseRecorder, name, boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    reqs, engines = _smoke_async_run(None, n=3)
+    assert all(e.phases is None for e in engines)
+    monkeypatch.undo()
+
+    cfg, params = _smoke_model()
+    tracer = Tracer()
+    Cluster(cfg, runtime="engine", params=params, chunk_size=16,
+            max_seq=128, max_batch=8, n_pages=256,
+            tracer=tracer).serve(copy.deepcopy(reqs))
+    new = {"submit"} | {n for v in STEP_PHASES.values() for n in v}
+    names = {e["name"] for e in tracer.events}
+    assert names and not names & new
+    assert not any("step" in e.get("args", {}) for e in tracer.events)
