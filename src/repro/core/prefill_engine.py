@@ -49,7 +49,7 @@ class PrefilledKV:
     """What the dispatcher ships to a decode instance.
 
     Paged backend: ``pages_k``/``pages_v`` hold the request's LIVE page
-    contents — (L, n_pages, page, kvh, hd) for the GQA layout, or the
+    contents — (L, n_pages, page, kvh * hd) for the GQA layout, or the
     (latent, rope-key) pair (L, n_pages, page, width) for MLA — plus
     ``kv_len`` valid tokens.  The receiver installs them into its own
     pool and builds a block-table row; for sliding-window configs the

@@ -31,22 +31,25 @@ def _interpret() -> bool:
 
 
 def prefill_attention(q, k_cache, v_cache, kv_len, q_offset, *,
-                      block_table=None, window: int = 0, causal: bool = True,
-                      block_q: int = 0, block_kv: int = 0):
+                      block_table=None, layer=None, window: int = 0,
+                      causal: bool = True, block_q: int = 0,
+                      block_kv: int = 0):
     """Chunked-prefill attention.
 
     Dense form (``block_table=None``): k_cache/v_cache are per-request
     (b, skv, kvh, hd) caches and ``q_offset`` is a (1,) shared chunk start.
 
     Paged form: k_cache/v_cache are the shared page pools
-    (n_pages, page, kvh, hd), ``block_table`` is (b, n_slots) physical
-    page ids and ``q_offset``/``kv_len`` are per-segment (b,) scalars —
-    one fused call covers a whole multi-request chunk.
+    (L, n_pages, page, kvh * hd) of which ``layer`` is read,
+    ``block_table`` is (b, n_slots) physical page ids and
+    ``q_offset``/``kv_len`` are per-segment (b,) scalars — one fused
+    call covers a whole multi-request chunk.
     """
     if block_table is not None:
         kwargs = {"block_q": block_q} if block_q else {}
         return paged_prefill_attention(
-            q, k_cache, v_cache, jnp.asarray(block_table),
+            q, k_cache, v_cache, jnp.asarray(layer),
+            jnp.asarray(block_table),
             jnp.asarray(kv_len), jnp.asarray(q_offset),
             window=window, causal=causal, interpret=_interpret(), **kwargs)
     kwargs = {}
@@ -59,19 +62,23 @@ def prefill_attention(q, k_cache, v_cache, kv_len, q_offset, *,
         window=window, causal=causal, interpret=_interpret(), **kwargs)
 
 
-def decode_attention(q, k_pool, v_pool, block_table, lens, *,
+def decode_attention(q, k_pool, v_pool, layer, block_table, lens, *,
                      window: int = 0):
+    """One query per request against layer ``layer`` of the
+    (L, n_pages, page, kvh * hd) pools."""
     return paged_decode_attention(
-        q, k_pool, v_pool, block_table, jnp.asarray(lens),
-        window=window, interpret=_interpret())
+        q, k_pool, v_pool, jnp.asarray(layer), block_table,
+        jnp.asarray(lens), window=window, interpret=_interpret())
 
 
-def cross_decode_attention(q, k_pool, v_pool, block_table, enc_lens):
+def cross_decode_attention(q, k_pool, v_pool, layer, block_table,
+                           enc_lens):
     """Non-causal decode attention over the read-only cross pages
-    (encoder K/V) via the per-request cross block table."""
+    (encoder K/V) of layer ``layer`` via the per-request cross block
+    table."""
     return paged_cross_decode_attention(
-        q, k_pool, v_pool, block_table, jnp.asarray(enc_lens),
-        interpret=_interpret())
+        q, k_pool, v_pool, jnp.asarray(layer), block_table,
+        jnp.asarray(enc_lens), interpret=_interpret())
 
 
 def mla_decode_attention(q_lat, q_rope, ckv_pool, kr_pool, block_table,

@@ -1,23 +1,19 @@
-"""Pallas TPU kernel: paged CROSS-attention decode (one token vs the
-read-only encoder pages).
+"""Paged CROSS-attention decode (one token vs the read-only encoder
+pages).
 
 VLM / encoder-decoder decode attends two KV populations per layer: the
 growing self-attention pages and a FIXED set of cross pages holding the
 encoder output's K/V (prefilled once per request, never appended to).
-This kernel streams the cross pages exactly like the self-attention
-paged-decode kernel streams live pages — the cross block table is a
-scalar-prefetch operand resolving the physical page per (request,
-page-slot) grid step — but the attention is non-causal: every decode
-query attends every valid encoder token, so the only mask is
-``tok < enc_len`` and there is no sliding-window skip.
+The cross pages are ordinary pages of the same pool, addressed by a
+second, read-only block table.  The attention is non-causal — every
+decode query attends every valid encoder token — so the only mask is
+``tok < enc_len`` and there is no sliding-window skip: exactly the
+paged-decode kernel with ``lens = enc_lens`` and no window, which this
+runs (same grid, page skipping and online softmax).
 
 Because the cross pages are read-only, consecutive decode iterations
 stream identical pages; the scatter the self-attention kernel needs per
 step never happens here.
-
-Grid: (batch, n_cross_slots) — page slots innermost; the per-(request,
-head-group) online-softmax state carries across the page dim in VMEM
-scratch, mirroring ``paged_decode_attention``.
 """
 from __future__ import annotations
 
@@ -25,102 +21,20 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-
-
-def _kernel(block_table_ref, enc_lens_ref,  # scalar prefetch
-            q_ref, k_ref, v_ref,            # VMEM blocks
-            o_ref,                          # VMEM out
-            m_ref, l_ref, acc_ref,          # VMEM scratch
-            *, page_size: int, n_slots: int, rep: int):
-    bi = pl.program_id(0)
-    pi = pl.program_id(1)
-
-    @pl.when(pi == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    enc_len = enc_lens_ref[bi]
-
-    # skip pages past the encoder length (pad slots may point at the
-    # scratch page — never read them); no causal / window skipping: the
-    # encoder output is fully visible to every decode query
-    @pl.when(pi * page_size < enc_len)
-    def _update():
-        q = q_ref[0].astype(jnp.float32)                 # (h, hd)
-        k = k_ref[0].astype(jnp.float32)                 # (page, kvh, hd)
-        v = v_ref[0].astype(jnp.float32)                 # (page, kvh, hd_v)
-        h, hd = q.shape
-        kvh = k.shape[1]
-        qg = q.reshape(kvh, rep, hd)
-        # scores: (kvh, rep, page)
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * (hd ** -0.5)
-        tok = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (kvh, rep, page_size), 2)
-        s = jnp.where(tok < enc_len, s, NEG_INF)
-        m_prev = m_ref[...]                              # (kvh, rep)
-        m_new = jnp.maximum(m_prev, s.max(axis=2))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=2)
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + pv
-        m_ref[...] = m_new
-
-    @pl.when(pi == n_slots - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-20)
-        out = acc_ref[...] / l[..., None]                # (kvh, rep, hd_v)
-        o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+from repro.kernels.paged_decode_attention import paged_decode_attention
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_cross_decode_attention(
         q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
-        block_table: jnp.ndarray, enc_lens: jnp.ndarray, *,
-        interpret: bool = False) -> jnp.ndarray:
+        layer: jnp.ndarray, block_table: jnp.ndarray,
+        enc_lens: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
     """q: (b, h, hd) one decode query per request; k_pool/v_pool:
-    (n_pages, page, kvh, hd) — the SHARED pool whose cross pages hold the
-    encoder K/V; block_table: (b, n_slots) the per-request read-only
-    cross block table (pad slots may point at a scratch page — masked by
-    ``enc_lens``); enc_lens: (b,) valid encoder tokens per request.
-    Returns (b, h, hd_v)."""
-    b, h, hd = q.shape
-    n_pages, page_size, kvh, hd_v = v_pool.shape
-    n_slots = block_table.shape[1]
-    rep = h // kvh
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_slots),
-        in_specs=[
-            pl.BlockSpec((1, h, hd), lambda bi, pi, bt, ln: (bi, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd),
-                         lambda bi, pi, bt, ln: (bt[bi, pi], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd_v),
-                         lambda bi, pi, bt, ln: (bt[bi, pi], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, hd_v),
-                               lambda bi, pi, bt, ln: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kvh, rep), jnp.float32),
-            pltpu.VMEM((kvh, rep), jnp.float32),
-            pltpu.VMEM((kvh, rep, hd_v), jnp.float32),
-        ])
-    kern = functools.partial(_kernel, page_size=page_size, n_slots=n_slots,
-                             rep=rep)
-    return pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, hd_v), q.dtype),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), enc_lens.astype(jnp.int32),
-      q, k_pool, v_pool)
+    (L, n_pages, page, kvh * hd) — the SHARED pool whose cross pages
+    hold the encoder K/V; layer: scalar layer to read; block_table:
+    (b, n_slots) the per-request read-only cross block table (pad slots
+    may point at a scratch page — masked by ``enc_lens``); enc_lens:
+    (b,) valid encoder tokens per request.  Returns (b, h, hd_v)."""
+    return paged_decode_attention(q, k_pool, v_pool, layer, block_table,
+                                  enc_lens, interpret=interpret)

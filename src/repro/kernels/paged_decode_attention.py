@@ -2,14 +2,17 @@
 
 The decode phase is memory-bound (§2.1): each step streams the whole KV
 cache once.  vLLM's PagedAttention CUDA kernel becomes, on TPU, a
-scalar-prefetched page gather: the block table is a scalar-prefetch
-operand, so the BlockSpec ``index_map`` of the K/V pools resolves the
-physical page for each (request, page-slot) grid step and Pallas streams
-exactly the live pages HBM->VMEM — no gather materialization.
+scalar-prefetched page gather: the layer and the block table are
+scalar-prefetch operands, so the BlockSpec ``index_map`` of the K/V
+pools resolves (layer, physical page) for each (request, page-slot)
+grid step and Pallas streams exactly the live pages HBM->VMEM — no
+gather materialization, and no slice of the layer out of the
+``(L, n_pages, page, kvh * hd)`` pool (kernels/pool_lanes.py).
 
-Page size defaults to 64 tokens so a (page x head_dim=128) tile is
-lane-aligned; the per-(request, head-group) online-softmax state lives in
-VMEM scratch and carries across the page grid dim.
+A page block is ``(page, kvh * hd)``; the kv heads are split in VMEM
+by lane block, each against its spread query heads.  The
+per-(request, head) online-softmax state lives in VMEM scratch and
+carries across the page grid dim.
 
 Grid: (batch, n_page_slots) — page slots innermost.
 """
@@ -22,15 +25,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.pool_lanes import (gather_heads, heads_per_block,
+                                      spread_heads)
+
 NEG_INF = -1e30
-DEFAULT_PAGE_SIZE = 64
 
 
-def _kernel(block_table_ref, lens_ref,      # scalar prefetch
-            q_ref, k_ref, v_ref,            # VMEM blocks
-            o_ref,                          # VMEM out
-            m_ref, l_ref, acc_ref,          # VMEM scratch
-            *, page_size: int, n_slots: int, rep: int, window: int):
+def _kernel(layer_ref, block_table_ref, lens_ref,   # scalar prefetch
+            q_ref, k_ref, v_ref,                    # VMEM blocks
+            o_ref,                                  # VMEM out
+            m_ref, l_ref, acc_ref,                  # VMEM scratch
+            *, page_size: int, n_slots: int, scale: float, window: int):
     bi = pl.program_id(0)
     pi = pl.program_id(1)
 
@@ -51,79 +56,84 @@ def _kernel(block_table_ref, lens_ref,      # scalar prefetch
 
     @pl.when(live)
     def _update():
-        q = q_ref[0].astype(jnp.float32)                 # (h, hd)
-        k = k_ref[0].astype(jnp.float32)                 # (page, kvh, hd)
-        v = v_ref[0].astype(jnp.float32)                 # (page, kvh, hd_v)
-        h, hd = q.shape
-        kvh = k.shape[1]
-        qg = q.reshape(kvh, rep, hd)
-        # scores: (kvh, rep, page)
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * (hd ** -0.5)
+        k = k_ref[0, 0].astype(jnp.float32)          # (page, kvh*hd)
+        v = v_ref[0, 0].astype(jnp.float32)          # (page, kvh*hd_v)
+        n_blk, rows, lb = q_ref.shape[1:]
+        lbv = acc_ref.shape[2]
         tok = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (kvh, rep, page_size), 2)
+            jnp.int32, (rows, page_size), 1)
         mask = tok < length
         if window:
             # the query sits at position length-1: keep k > q - window
             mask = jnp.logical_and(mask, tok > length - 1 - window)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]                              # (kvh, rep)
-        m_new = jnp.maximum(m_prev, s.max(axis=2))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=2)
-        # pv: (kvh, rep, hd_v)
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + pv
-        m_ref[...] = m_new
+        for j in range(n_blk):                       # lane blocks
+            q = q_ref[0, j].astype(jnp.float32)      # (rows, lb) spread
+            s = jax.lax.dot_general(
+                q, k[:, j * lb:(j + 1) * lb], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(mask, s, NEG_INF)           # (rows, page)
+            m_prev = m_ref[j]                         # (rows, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[j] = l_ref[j] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[j] = acc_ref[j] * corr + jax.lax.dot_general(
+                p, v[:, j * lbv:(j + 1) * lbv], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[j] = m_new
 
     @pl.when(pi == n_slots - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-20)
-        out = acc_ref[...] / l[..., None]                # (kvh, rep, hd_v)
-        o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_attention(
         q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
-        block_table: jnp.ndarray, lens: jnp.ndarray, *,
-        window: int = 0, interpret: bool = False) -> jnp.ndarray:
-    """q: (b, h, hd); k_pool/v_pool: (n_pages, page, kvh, hd); block_table:
+        layer: jnp.ndarray, block_table: jnp.ndarray, lens: jnp.ndarray,
+        *, window: int = 0, interpret: bool = False) -> jnp.ndarray:
+    """q: (b, h, hd); k_pool/v_pool: (L, n_pages, page, kvh * hd) —
+    every layer's pool; layer: scalar layer to read; block_table:
     (b, n_slots) physical page ids (pad slots and slots that slid out of
     ``window`` may point at a scratch page — they are masked/skipped);
     lens: (b,) tokens in cache per request; window: sliding window in
     tokens (0 = unlimited).  Returns (b, h, hd_v)."""
     b, h, hd = q.shape
-    n_pages, page_size, kvh, hd_v = v_pool.shape
+    _, _, page_size, w = k_pool.shape
+    kvh = w // hd
+    wv = v_pool.shape[3]
+    g = heads_per_block(kvh, hd)
+    n_blk, rows = kvh // g, g * h // kvh
+    lb, lbv = g * hd, g * wv // kvh
     n_slots = block_table.shape[1]
-    rep = h // kvh
+    qs = spread_heads(q, kvh, g).reshape(b, n_blk, rows, lb)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_slots),
         in_specs=[
-            pl.BlockSpec((1, h, hd), lambda bi, pi, bt, ln: (bi, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd),
-                         lambda bi, pi, bt, ln: (bt[bi, pi], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd_v),
-                         lambda bi, pi, bt, ln: (bt[bi, pi], 0, 0, 0)),
+            pl.BlockSpec((1, n_blk, rows, lb),
+                         lambda bi, pi, *_: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, w),
+                         lambda bi, pi, ly, bt, ln: (ly[0], bt[bi, pi], 0, 0)),
+            pl.BlockSpec((1, 1, page_size, wv),
+                         lambda bi, pi, ly, bt, ln: (ly[0], bt[bi, pi], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, hd_v),
-                               lambda bi, pi, bt, ln: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, n_blk, rows, lbv),
+                               lambda bi, pi, *_: (bi, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((kvh, rep), jnp.float32),
-            pltpu.VMEM((kvh, rep), jnp.float32),
-            pltpu.VMEM((kvh, rep, hd_v), jnp.float32),
+            pltpu.VMEM((n_blk, rows, 1), jnp.float32),
+            pltpu.VMEM((n_blk, rows, 1), jnp.float32),
+            pltpu.VMEM((n_blk, rows, lbv), jnp.float32),
         ])
     kern = functools.partial(_kernel, page_size=page_size, n_slots=n_slots,
-                             rep=rep, window=window)
-    return pl.pallas_call(
+                             scale=hd ** -0.5, window=window)
+    out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, hd_v), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n_blk, rows, lbv), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), lens.astype(jnp.int32),
-      q, k_pool, v_pool)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      block_table.astype(jnp.int32), lens.astype(jnp.int32),
+      qs, k_pool, v_pool)
+    return gather_heads(out.reshape(b, h, lbv), kvh, g)

@@ -2,25 +2,26 @@
 
 The serving-path companion of ``chunked_prefill_attention``: instead of a
 dense per-request (b, skv, kvh, hd) cache, K/V live in the shared device
-page pool (n_pages, page, kvh, hd) and each packed segment addresses its
-pages through a block table.  This is what lets one fused call execute a
-whole fixed-size chunk whose segments belong to *different* requests —
-the batch dim is "segments of the current chunk", each with its own
-``q_offset`` (absolute position of the segment start) and ``kv_len``
-(valid tokens after this segment is appended).
+page pool (L, n_pages, page, kvh * hd) and each packed segment addresses
+its pages through a block table.  This is what lets one fused call
+execute a whole fixed-size chunk whose segments belong to *different*
+requests — the batch dim is "segments of the current chunk", each with
+its own ``q_offset`` (absolute position of the segment start) and
+``kv_len`` (valid tokens after this segment is appended).
 
-TPU adaptation: the block table is a scalar-prefetch operand, so the K/V
-BlockSpec ``index_map`` resolves the physical page for each
-(segment, page-slot) grid step and Pallas streams exactly the live pages
-HBM->VMEM — the kv block size IS the page size.  Blocks carry ALL heads,
+TPU adaptation: the layer and the block table are scalar-prefetch
+operands, so the K/V BlockSpec ``index_map`` resolves (layer, physical
+page) for each (segment, page-slot) grid step and Pallas streams exactly
+the live pages HBM->VMEM — the kv block size IS the page size, and the
+pool is read as it lies, every layer in one array
+(kernels/pool_lanes.py).  A page block is (page, kvh * hd), whole rows,
 so Mosaic's rule that the last two block dims divide by (8, 128) or
-equal the array's holds at any head count (a one-head block over
-qwen2's 14 heads is refused): a page block is (page, kvh, hd) of the
-pool as it lies, and q / out go head-major around the call, so a q block
-is (h, block_q, hd) and each head's (block_q, hd) slice is a plain tile
-(Mosaic refuses a bf16 store into one head of a (block_q, h, 64) block).
-The kernel loops over heads statically; per-head online-softmax state
-lives in VMEM scratch and carries across the page grid dim.
+equal the array's holds at any head count; the kernel splits the kv
+heads by lane block in VMEM.  q / out go head-major around the call, so
+a q block is (h, block_q, lanes) and each head's (block_q, lanes) slice
+is a plain tile.  The kernel loops over heads statically; per-head
+online-softmax state lives in VMEM scratch and carries across the page
+grid dim.
 
 Grid: (segments, q_blocks, page_slots); page slots innermost.
 """
@@ -33,16 +34,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.pool_lanes import (gather_heads, heads_per_block,
+                                      spread_heads)
+
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
 
 
-def _kernel(bt_ref, kv_len_ref, q_off_ref,  # scalar prefetch
-            q_ref, k_ref, v_ref,            # VMEM blocks
-            o_ref,                          # VMEM out block
-            m_ref, l_ref, acc_ref,          # VMEM scratch
-            *, block_q: int, page_size: int, n_slots: int, rep: int,
-            window: int, causal: bool):
+def _kernel(layer_ref, bt_ref, kv_len_ref, q_off_ref,  # scalar prefetch
+            q_ref, k_ref, v_ref,                        # VMEM blocks
+            o_ref,                                      # VMEM out block
+            m_ref, l_ref, acc_ref,                      # VMEM scratch
+            *, block_q: int, page_size: int, n_slots: int, rows: int,
+            scale: float, window: int, causal: bool):
     bi = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -83,12 +87,12 @@ def _kernel(bt_ref, kv_len_ref, q_off_ref,  # scalar prefetch
         # f32 pools ask for the full-precision contraction
         prec = (jax.lax.Precision.HIGHEST if k_ref.dtype == jnp.float32
                 else jax.lax.Precision.DEFAULT)
-        scale = q_ref.shape[-1] ** -0.5
-        for g in range(k_ref.shape[2]):                  # kv heads
-            k = k_ref[0, :, g, :]                        # (page, hd)
-            v = v_ref[0, :, g, :]                        # (page, hd_v)
-            for hi in range(g * rep, (g + 1) * rep):     # its q heads
-                q = q_ref[0, hi].astype(k.dtype)         # (bq, hd)
+        lb, lbv = q_ref.shape[-1], acc_ref.shape[-1]
+        for j in range(q_ref.shape[1] // rows):          # lane blocks
+            k = k_ref[0, 0, :, j * lb:(j + 1) * lb]      # (page, lb)
+            v = v_ref[0, 0, :, j * lbv:(j + 1) * lbv]    # (page, lbv)
+            for hi in range(j * rows, (j + 1) * rows):   # its q heads
+                q = q_ref[0, hi].astype(k.dtype)         # (bq, lb) spread
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())), precision=prec,
                     preferred_element_type=jnp.float32) * scale
@@ -115,49 +119,58 @@ def _kernel(bt_ref, kv_len_ref, q_off_ref,  # scalar prefetch
     static_argnames=("window", "causal", "block_q", "interpret"))
 def paged_prefill_attention(
         q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
-        block_table: jnp.ndarray, kv_len: jnp.ndarray,
+        layer: jnp.ndarray, block_table: jnp.ndarray, kv_len: jnp.ndarray,
         q_offset: jnp.ndarray, *,
         window: int = 0, causal: bool = True,
         block_q: int = DEFAULT_BLOCK_Q,
         interpret: bool = False) -> jnp.ndarray:
-    """q: (segs, sq, h, hd); k_pool/v_pool: (n_pages, page, kvh, hd) with
-    each segment's tokens already scattered into its pages; block_table:
-    (segs, n_slots) physical page ids (pad slots may repeat a live or
-    scratch page — masked by ``kv_len``); kv_len: (segs,) valid tokens
-    after the segment append; q_offset: (segs,) absolute position of each
+    """q: (segs, sq, h, hd); k_pool/v_pool: (L, n_pages, page, kvh * hd)
+    — every layer's pool, each segment's tokens already scattered into
+    its pages; layer: scalar layer to read; block_table: (segs, n_slots)
+    physical page ids (pad slots may repeat a live or scratch page —
+    masked by ``kv_len``); kv_len: (segs,) valid tokens after the
+    segment append; q_offset: (segs,) absolute position of each
     segment's first query.  Returns (segs, sq, h, hd_v)."""
     b, sq, h, hd = q.shape
-    n_pages, page_size, kvh, hd_v = v_pool.shape
+    _, _, page_size, w = k_pool.shape
+    kvh = w // hd
+    wv = v_pool.shape[3]
+    g = heads_per_block(kvh, hd)
+    lb, lbv = g * hd, g * wv // kvh
     n_slots = block_table.shape[1]
     block_q = min(block_q, sq)
     assert sq % block_q == 0, (sq, block_q)
     nq = sq // block_q
+    qs = spread_heads(q, kvh, g).transpose(0, 2, 1, 3)  # (segs, h, sq, lb)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, nq, n_slots),
         in_specs=[
-            pl.BlockSpec((1, h, block_q, hd),
+            pl.BlockSpec((1, h, block_q, lb),
                          lambda bi, qi, ki, *_: (bi, 0, qi, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd),
-                         lambda bi, qi, ki, bt, *_: (bt[bi, ki], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, kvh, hd_v),
-                         lambda bi, qi, ki, bt, *_: (bt[bi, ki], 0, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, w),
+                         lambda bi, qi, ki, ly, bt, *_:
+                         (ly[0], bt[bi, ki], 0, 0)),
+            pl.BlockSpec((1, 1, page_size, wv),
+                         lambda bi, qi, ki, ly, bt, *_:
+                         (ly[0], bt[bi, ki], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, block_q, hd_v),
+        out_specs=pl.BlockSpec((1, h, block_q, lbv),
                                lambda bi, qi, ki, *_: (bi, 0, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, block_q, 1), jnp.float32),
             pltpu.VMEM((h, block_q, 1), jnp.float32),
-            pltpu.VMEM((h, block_q, hd_v), jnp.float32),
+            pltpu.VMEM((h, block_q, lbv), jnp.float32),
         ])
     kern = functools.partial(
         _kernel, block_q=block_q, page_size=page_size, n_slots=n_slots,
-        rep=h // kvh, window=window, causal=causal)
+        rows=g * h // kvh, scale=hd ** -0.5, window=window, causal=causal)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, hd_v), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, lbv), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), kv_len.astype(jnp.int32),
-      q_offset.astype(jnp.int32), q.transpose(0, 2, 1, 3), k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      block_table.astype(jnp.int32), kv_len.astype(jnp.int32),
+      q_offset.astype(jnp.int32), qs, k_pool, v_pool)
+    return gather_heads(out.transpose(0, 2, 1, 3), kvh, g)

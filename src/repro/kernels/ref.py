@@ -30,23 +30,32 @@ def ref_chunked_prefill_attention(q, k_cache, v_cache, kv_len, q_offset, *,
     return out.reshape(b, sq, h, hd_v).astype(q.dtype)
 
 
-def ref_paged_prefill_attention(q, k_pool, v_pool, block_table, kv_len,
-                                q_offset, *, window: int = 0,
+def _gather_pages(pool, layer, block_table, kvh):
+    """Layer ``layer`` of an (L, n_pages, page, kvh * hd) pool gathered
+    through ``block_table`` into dense (b, n_slots * page, kvh, hd)."""
+    b, n_slots = block_table.shape
+    _, _, page, w = pool.shape
+    return pool[layer][block_table].reshape(b, n_slots * page, kvh,
+                                            w // kvh)
+
+
+def ref_paged_prefill_attention(q, k_pool, v_pool, layer, block_table,
+                                kv_len, q_offset, *, window: int = 0,
                                 causal: bool = True):
     """Oracle for kernels.paged_prefill_attention: gather each segment's
     pages densely, then run the dense chunked-prefill oracle with
     per-segment ``q_offset``."""
     b, sq, h, hd = q.shape
-    n_pages, page, kvh, hd_v = v_pool.shape
-    n_slots = block_table.shape[1]
+    kvh = k_pool.shape[3] // hd
     rep = h // kvh
-    k = k_pool[block_table].reshape(b, n_slots * page, kvh, hd)
-    v = v_pool[block_table].reshape(b, n_slots * page, kvh, hd_v)
+    k = _gather_pages(k_pool, layer, block_table, kvh)
+    v = _gather_pages(v_pool, layer, block_table, kvh)
+    hd_v = v.shape[3]
     qf = q.astype(jnp.float32).reshape(b, sq, kvh, rep, hd)
     s = jnp.einsum("bqgrd,bkgd->bgrqk", qf, k.astype(jnp.float32)) \
         * hd ** -0.5
     q_pos = q_offset[:, None] + jnp.arange(sq)[None, :]       # (b, sq)
-    k_pos = jnp.arange(n_slots * page)
+    k_pos = jnp.arange(k.shape[1])
     mask = k_pos[None, None, :] < kv_len[:, None, None]       # (b,1,K)
     if causal:
         mask = mask & (q_pos[:, :, None] >= k_pos[None, None, :])
@@ -58,19 +67,19 @@ def ref_paged_prefill_attention(q, k_pool, v_pool, block_table, kv_len,
     return out.reshape(b, sq, h, hd_v).astype(q.dtype)
 
 
-def ref_paged_decode_attention(q, k_pool, v_pool, block_table, lens, *,
-                               window: int = 0):
+def ref_paged_decode_attention(q, k_pool, v_pool, layer, block_table,
+                               lens, *, window: int = 0):
     """Oracle for kernels.paged_decode_attention: gather pages densely,
     then masked single-token attention."""
     b, h, hd = q.shape
-    n_pages, page, kvh, hd_v = v_pool.shape
-    n_slots = block_table.shape[1]
+    kvh = k_pool.shape[3] // hd
     rep = h // kvh
-    k = k_pool[block_table].reshape(b, n_slots * page, kvh, hd)
-    v = v_pool[block_table].reshape(b, n_slots * page, kvh, hd_v)
+    k = _gather_pages(k_pool, layer, block_table, kvh)
+    v = _gather_pages(v_pool, layer, block_table, kvh)
+    hd_v = v.shape[3]
     qf = q.astype(jnp.float32).reshape(b, kvh, rep, hd)
     s = jnp.einsum("bgrd,bkgd->bgrk", qf, k.astype(jnp.float32)) * hd ** -0.5
-    tok = jnp.arange(n_slots * page)
+    tok = jnp.arange(k.shape[1])
     mask = tok[None, :] < lens[:, None]
     if window:
         mask = mask & (tok[None, :] > lens[:, None] - 1 - window)
@@ -80,19 +89,19 @@ def ref_paged_decode_attention(q, k_pool, v_pool, block_table, lens, *,
     return out.reshape(b, h, hd_v).astype(q.dtype)
 
 
-def ref_paged_cross_decode_attention(q, k_pool, v_pool, block_table,
-                                     enc_lens):
+def ref_paged_cross_decode_attention(q, k_pool, v_pool, layer,
+                                     block_table, enc_lens):
     """Oracle for kernels.paged_cross_decode_attention: gather the cross
     pages densely, non-causal masked attention over the encoder length."""
     b, h, hd = q.shape
-    n_pages, page, kvh, hd_v = v_pool.shape
-    n_slots = block_table.shape[1]
+    kvh = k_pool.shape[3] // hd
     rep = h // kvh
-    k = k_pool[block_table].reshape(b, n_slots * page, kvh, hd)
-    v = v_pool[block_table].reshape(b, n_slots * page, kvh, hd_v)
+    k = _gather_pages(k_pool, layer, block_table, kvh)
+    v = _gather_pages(v_pool, layer, block_table, kvh)
+    hd_v = v.shape[3]
     qf = q.astype(jnp.float32).reshape(b, kvh, rep, hd)
     s = jnp.einsum("bgrd,bkgd->bgrk", qf, k.astype(jnp.float32)) * hd ** -0.5
-    tok = jnp.arange(n_slots * page)
+    tok = jnp.arange(k.shape[1])
     mask = tok[None, :] < enc_lens[:, None]
     s = jnp.where(mask[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)

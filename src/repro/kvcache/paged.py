@@ -7,14 +7,14 @@ Two layers, mirroring vLLM's split (§2.1, [21]):
   accounting.  The decode-instance schedulers (greedy / reserve-static /
   reserve-dynamic, §3.4) make admission decisions against this, and the
   cluster monitor broadcasts its load.
-* ``PagePool`` — the device-side tensors (layers, n_pages, page, kvh, hd)
-  plus jit'd scatter/gather ops.  The serving engines attend against it
-  through kernels/paged_prefill_attention (fused chunk prefill) and
-  kernels/paged_decode_attention (batched decode); ``gather``/``install``
-  are the page-granular KV-transfer endpoints.  Engines reserve one extra
-  physical page past the allocator's range as a scratch ("trash") page:
-  pad tokens and dead slots scatter there and no block table references
-  it.
+* ``PagePool`` — the device-side tensors (layers, n_pages, page,
+  kvh * hd) plus jit'd scatter/gather ops.  The serving engines attend
+  against it through kernels/paged_prefill_attention (fused chunk
+  prefill) and kernels/paged_decode_attention (batched decode);
+  ``gather``/``install`` are the page-granular KV-transfer endpoints.
+  Engines reserve one extra physical page past the allocator's range as
+  a scratch ("trash") page: pad tokens and dead slots scatter there and
+  no block table references it.
 
 Ownership model (docs/prefix_cache.md): every physical page carries a
 refcount — one per block table referencing it plus one if a cache entry
@@ -622,7 +622,11 @@ class PagedAllocator:
 class PagePool:
     """Per-layer K/V page pools.
 
-    GQA layout (``create``): k/v are (L, n_pages, page, kvh, hd).
+    GQA layout (``create``): k/v are (L, n_pages, page, kvh * hd) — a
+    token's kv heads side by side on the last (lane) axis, the layout
+    the paged kernels read and write in place: the row is a whole
+    number of 128-lane tiles for every GQA config, so the device keeps
+    the pool row-major and unpadded (docs/paged_kv.md).
     MLA latent layout (``create_latent``): the pair is reused as
     (compressed latent, decoupled-RoPE key) — k: (L, n_pages, page,
     kv_lora_rank), v: (L, n_pages, page, qk_rope_head_dim).  All pool
@@ -631,7 +635,7 @@ class PagePool:
     the latent pages are just ~an order of magnitude narrower.
 
     Cross-attention KV (VLM / enc-dec archs) shares the GQA pool: the
-    encoder K/V per cross layer has the same (page, kvh, hd) tile shape,
+    encoder K/V per cross layer has the same (page, kvh * hd) tile shape,
     so cross pages are ordinary pool pages referenced by a second,
     read-only block table per request (see ``PagedAllocator``).
     """
@@ -641,7 +645,7 @@ class PagePool:
     @classmethod
     def create(cls, n_layers: int, n_pages: int, page_size: int, kvh: int,
                hd: int, dtype=jnp.bfloat16) -> "PagePool":
-        shape = (n_layers, n_pages, page_size, kvh, hd)
+        shape = (n_layers, n_pages, page_size, kvh * hd)
         return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
     @classmethod
@@ -662,20 +666,22 @@ class PagePool:
     def write_chunk(self, layer: int, pages: np.ndarray, k_chunk, v_chunk
                     ) -> "PagePool":
         """Write a page-aligned chunk. pages: (chunk//page,) physical ids;
-        k_chunk/v_chunk: (chunk, kvh, hd)."""
+        k_chunk/v_chunk: (chunk, kvh, hd) or (chunk, width)."""
         ps = self.page_size
-        kc = k_chunk.reshape(-1, ps, *k_chunk.shape[1:]).astype(self.k.dtype)
-        vc = v_chunk.reshape(-1, ps, *v_chunk.shape[1:]).astype(self.v.dtype)
+        kc = k_chunk.reshape(-1, ps, self.k.shape[3]).astype(self.k.dtype)
+        vc = v_chunk.reshape(-1, ps, self.v.shape[3]).astype(self.v.dtype)
         pages = jnp.asarray(pages)
         return PagePool(k=self.k.at[layer, pages].set(kc),
                         v=self.v.at[layer, pages].set(vc))
 
     def write_token(self, layer: int, page: int, offset: int, k_tok, v_tok
                     ) -> "PagePool":
-        """k_tok/v_tok: (kvh, hd)."""
+        """k_tok/v_tok: (kvh, hd) or (width,)."""
         return PagePool(
-            k=self.k.at[layer, page, offset].set(k_tok.astype(self.k.dtype)),
-            v=self.v.at[layer, page, offset].set(v_tok.astype(self.v.dtype)))
+            k=self.k.at[layer, page, offset].set(
+                k_tok.reshape(-1).astype(self.k.dtype)),
+            v=self.v.at[layer, page, offset].set(
+                v_tok.reshape(-1).astype(self.v.dtype)))
 
     def layer(self, layer: int):
         return self.k[layer], self.v[layer]
@@ -684,14 +690,14 @@ class PagePool:
     def gather(self, pages):
         """Extract the page contents for one request — what the prefill
         instance ships to decode.  pages: (n,) physical ids.
-        Returns (k, v) of shape (L, n, page, kvh, hd)."""
+        Returns (k, v) of shape (L, n, page, kvh * hd)."""
         idx = jnp.asarray(pages)
         return self.k[:, idx], self.v[:, idx]
 
     def install(self, pages, k_pages, v_pages) -> "PagePool":
         """Install received page contents (all layers at once) into local
         physical pages — decode-side admission.  pages: (n,) ids;
-        k_pages/v_pages: (L, n, page, kvh, hd).  Jitted with the pools
+        k_pages/v_pages: (L, n, page, kvh * hd).  Jitted with the pools
         donated: XLA scatters in place instead of copying both whole
         pool tensors per admitted batch (no-op on CPU)."""
         k, v = _install_pages(self.k, self.v, jnp.asarray(pages),

@@ -254,49 +254,55 @@ def gqa_prefill(p: dict, cfg: ModelConfig, x: jnp.ndarray, cache: dict, *,
 
 
 def gqa_prefill_paged(p: dict, cfg: ModelConfig, x: jnp.ndarray,
-                      k_layer: jnp.ndarray, v_layer: jnp.ndarray, *,
+                      k_pool: jnp.ndarray, v_pool: jnp.ndarray, layer, *,
                       positions, q_offset, kv_len, block_tables,
                       pages_idx, offs_idx, window: int = 0):
-    """Fused chunk prefill against one layer's page pool.
+    """Fused chunk prefill against layer ``layer`` of the page pool.
 
     x: (segs, sq, d) — the packed segments of one fixed-size chunk;
-    k_layer/v_layer: (n_pages, page, kvh, hd) this layer's pool;
+    k_pool/v_pool: (L, n_pages, page, kvh * hd) every layer's pool;
     positions: (segs, sq) absolute token positions;
     pages_idx/offs_idx: (segs, sq) physical (page, in-page) slot per
     token (pad tokens point at the engine's scratch page).  The chunk's
-    K/V is scattered into the pool first, then the Pallas paged-prefill
-    kernel attends over (written prefix ++ this chunk) through the block
-    tables.  Returns (attn_out, k_layer, v_layer).
+    K/V is scattered into the pool in place first, then the Pallas
+    paged-prefill kernel attends over (written prefix ++ this chunk)
+    through the block tables.  Returns (attn_out, k_pool, v_pool).
     """
     from repro.kernels import ops
     b, s, _ = x.shape
     q, k, v = gqa_qkv(p, cfg, x, positions)
-    k_layer = k_layer.at[pages_idx, offs_idx].set(k.astype(k_layer.dtype))
-    v_layer = v_layer.at[pages_idx, offs_idx].set(v.astype(v_layer.dtype))
-    out = ops.prefill_attention(q, k_layer, v_layer, kv_len, q_offset,
-                                block_table=block_tables, window=window)
-    return out.reshape(b, s, -1) @ p["wo"], k_layer, v_layer
+    k_pool = k_pool.at[layer, pages_idx, offs_idx].set(
+        k.reshape(b, s, -1).astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, pages_idx, offs_idx].set(
+        v.reshape(b, s, -1).astype(v_pool.dtype))
+    out = ops.prefill_attention(q, k_pool, v_pool, kv_len, q_offset,
+                                block_table=block_tables, layer=layer,
+                                window=window)
+    return out.reshape(b, s, -1) @ p["wo"], k_pool, v_pool
 
 
 def gqa_decode_paged(p: dict, cfg: ModelConfig, x: jnp.ndarray,
-                     k_layer: jnp.ndarray, v_layer: jnp.ndarray, *,
+                     k_pool: jnp.ndarray, v_pool: jnp.ndarray, layer, *,
                      pos, pages, offs, block_tables, lens,
                      window: int = 0):
-    """Batched one-token decode against one layer's page pool.
+    """Batched one-token decode against layer ``layer`` of the page pool.
 
-    x: (slots, 1, d); pos: (slots,) append position per slot;
-    pages/offs: (slots,) physical slot of the appended token (dead slots
-    point at the scratch page); lens: (slots,) valid tokens incl. the
-    append.  Returns (attn_out, k_layer, v_layer).
+    x: (slots, 1, d); k_pool/v_pool: (L, n_pages, page, kvh * hd);
+    pos: (slots,) append position per slot; pages/offs: (slots,)
+    physical slot of the appended token (dead slots point at the scratch
+    page); lens: (slots,) valid tokens incl. the append.
+    Returns (attn_out, k_pool, v_pool).
     """
     from repro.kernels import ops
     b = x.shape[0]
     q, k, v = gqa_qkv(p, cfg, x, pos[:, None])
-    k_layer = k_layer.at[pages, offs].set(k[:, 0].astype(k_layer.dtype))
-    v_layer = v_layer.at[pages, offs].set(v[:, 0].astype(v_layer.dtype))
-    out = ops.decode_attention(q[:, 0], k_layer, v_layer, block_tables,
-                               lens, window=window)
-    return out.reshape(b, 1, -1) @ p["wo"], k_layer, v_layer
+    k_pool = k_pool.at[layer, pages, offs].set(
+        k.reshape(b, -1).astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, pages, offs].set(
+        v.reshape(b, -1).astype(v_pool.dtype))
+    out = ops.decode_attention(q[:, 0], k_pool, v_pool, layer,
+                               block_tables, lens, window=window)
+    return out.reshape(b, 1, -1) @ p["wo"], k_pool, v_pool
 
 
 def gqa_decode(p: dict, cfg: ModelConfig, x: jnp.ndarray, cache: dict,
@@ -644,34 +650,31 @@ def cross_forward(p: dict, cfg: ModelConfig, x: jnp.ndarray,
 
 
 def cross_prefill_paged(p: dict, cfg: ModelConfig, x: jnp.ndarray,
-                        k_layer: jnp.ndarray, v_layer: jnp.ndarray, *,
+                        k_pool: jnp.ndarray, v_pool: jnp.ndarray, layer, *,
                         enc_h, cross_bt, cross_len, cross_pg, cross_off):
     """Cross-attention sublayer of one fused paged prefill chunk.
 
     x: (segs, sq, d) normed decoder activations; enc_h: (segs, enc_ctx,
     d) encoder output per segment; cross_bt: (segs, cross_slots) the
     read-only cross block table; cross_pg/cross_off: (segs, enc_ctx)
-    physical (page, in-page) slot for the one-shot cross-KV write —
-    segments past their request's first chunk point these at the scratch
-    page, so the encoder K/V is prefilled exactly once per request.
-    The read is non-causal: every decoder query attends all ``cross_len``
-    encoder tokens through the block table.
-    Returns (attn_out, k_layer, v_layer)."""
-    from repro.kernels import ops
-    b, s, _ = x.shape
-    h, hd = cfg.n_heads, cfg.resolved_head_dim
+    physical (page, in-page) slot for the one-shot cross-KV write into
+    layer ``layer`` of the pool — segments past their request's first
+    chunk point these at the scratch page, so the encoder K/V is
+    prefilled exactly once per request.  The read is non-causal: every
+    decoder query attends all ``cross_len`` encoder tokens through the
+    block table.  Returns (attn_out, k_pool, v_pool)."""
+    b, e, _ = enc_h.shape
     ck, cv = cross_kv(p, cfg, enc_h)
-    k_layer = k_layer.at[cross_pg, cross_off].set(ck.astype(k_layer.dtype))
-    v_layer = v_layer.at[cross_pg, cross_off].set(cv.astype(v_layer.dtype))
-    q = (x @ p["wq"]).reshape(b, s, h, hd)
-    out = ops.prefill_attention(
-        q, k_layer, v_layer, cross_len,
-        jnp.zeros_like(cross_len), block_table=cross_bt, causal=False)
-    return out.reshape(b, s, -1) @ p["wo"], k_layer, v_layer
+    k_pool = k_pool.at[layer, cross_pg, cross_off].set(
+        ck.reshape(b, e, -1).astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, cross_pg, cross_off].set(
+        cv.reshape(b, e, -1).astype(v_pool.dtype))
+    return cross_attend_paged(p, cfg, x, k_pool, v_pool, layer,
+                              cross_bt=cross_bt, cross_len=cross_len)
 
 
 def cross_attend_paged(p: dict, cfg: ModelConfig, x: jnp.ndarray,
-                       k_layer: jnp.ndarray, v_layer: jnp.ndarray, *,
+                       k_pool: jnp.ndarray, v_pool: jnp.ndarray, layer, *,
                        cross_bt, cross_len):
     """Read-only cross-attention sublayer of a fused paged prefill
     chunk: the chunk carries NO encoder work — every segment's cross
@@ -679,29 +682,29 @@ def cross_attend_paged(p: dict, cfg: ModelConfig, x: jnp.ndarray,
     scattered them earlier, or they were aliased from the cross-page
     cache), so the encoder stack and the one-shot scatter are skipped
     entirely.  Same read as ``cross_prefill_paged``.
-    Returns (attn_out, k_layer, v_layer)."""
+    Returns (attn_out, k_pool, v_pool)."""
     from repro.kernels import ops
     b, s, _ = x.shape
     h, hd = cfg.n_heads, cfg.resolved_head_dim
     q = (x @ p["wq"]).reshape(b, s, h, hd)
     out = ops.prefill_attention(
-        q, k_layer, v_layer, cross_len,
-        jnp.zeros_like(cross_len), block_table=cross_bt, causal=False)
-    return out.reshape(b, s, -1) @ p["wo"], k_layer, v_layer
+        q, k_pool, v_pool, cross_len, jnp.zeros_like(cross_len),
+        block_table=cross_bt, layer=layer, causal=False)
+    return out.reshape(b, s, -1) @ p["wo"], k_pool, v_pool
 
 
 def cross_decode_paged(p: dict, cfg: ModelConfig, x: jnp.ndarray,
-                       k_layer: jnp.ndarray, v_layer: jnp.ndarray, *,
+                       k_pool: jnp.ndarray, v_pool: jnp.ndarray, layer, *,
                        cross_bt, cross_len):
     """Batched one-token cross attention against the read-only cross
-    pages — no scatter: the encoder K/V was installed at admission and
-    never changes.  x: (slots, 1, d); cross_bt: (slots, cross_slots);
-    cross_len: (slots,) encoder tokens per slot (0 for empty slots).
-    Returns (attn_out, k_layer, v_layer)."""
+    pages of layer ``layer`` — no scatter: the encoder K/V was installed
+    at admission and never changes.  x: (slots, 1, d); cross_bt:
+    (slots, cross_slots); cross_len: (slots,) encoder tokens per slot
+    (0 for empty slots).  Returns (attn_out, k_pool, v_pool)."""
     from repro.kernels import ops
     b = x.shape[0]
     h, hd = cfg.n_heads, cfg.resolved_head_dim
     q = (x @ p["wq"]).reshape(b, 1, h, hd)
-    out = ops.cross_decode_attention(q[:, 0], k_layer, v_layer, cross_bt,
-                                     cross_len)
-    return out.reshape(b, 1, -1) @ p["wo"], k_layer, v_layer
+    out = ops.cross_decode_attention(q[:, 0], k_pool, v_pool, layer,
+                                     cross_bt, cross_len)
+    return out.reshape(b, 1, -1) @ p["wo"], k_pool, v_pool

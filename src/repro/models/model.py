@@ -387,55 +387,65 @@ def paged_supported(cfg: ModelConfig) -> bool:
     return not (cfg.mla is not None and CROSS_ATTN in kinds)
 
 
-def _paged_attn_block(p, cfg: ModelConfig, x, k_layer, v_layer, attn,
+def _paged_attn_block(p, cfg: ModelConfig, x, k_pool, v_pool, layer, attn,
                       cross=None):
     """One ATTN/CROSS_ATTN block (norm, attention-vs-pool, optional
     cross-attention-vs-cross-pages, MLP/MoE) on the paged path.
-    ``attn(p_attn, h, k_layer, v_layer)`` performs the pool scatter +
-    kernel call for the current mode; ``cross(p_cross, hc, k_layer,
-    v_layer)`` does the same against the request's read-only cross
-    block table (CROSS_ATTN blocks only — the ``"cross" in p`` check is
-    structural, so non-cross layers trace no cross code)."""
+    ``attn(p_attn, h, k_pool, v_pool, layer)`` performs the pool scatter
+    + kernel call for the current mode at layer ``layer``;
+    ``cross(p_cross, hc, k_pool, v_pool, layer)`` does the same against
+    the request's read-only cross block table (CROSS_ATTN blocks only —
+    the ``"cross" in p`` check is structural, so non-cross layers trace
+    no cross code)."""
     h = B.rms_norm(x, p["norm1"], cfg.norm_eps)
-    a, k_layer, v_layer = attn(p["attn"], h, k_layer, v_layer)
+    a, k_pool, v_pool = attn(p["attn"], h, k_pool, v_pool, layer)
     x = x + a
     if cross is not None and "cross" in p:
         hc = B.rms_norm(x, p["norm_c"], cfg.norm_eps)
-        ac, k_layer, v_layer = cross(p["cross"], hc, k_layer, v_layer)
+        ac, k_pool, v_pool = cross(p["cross"], hc, k_pool, v_pool, layer)
         x = x + ac
     h2 = B.rms_norm(x, p["norm2"], cfg.norm_eps)
     if "moe" in p:
         m, _ = MLP.moe_forward(p["moe"], cfg, h2)
     else:
         m = MLP.mlp_forward(p["mlp"], cfg, h2)
-    return x + m, k_layer, v_layer
+    return x + m, k_pool, v_pool
 
 
-def _run_layers_paged(params, cfg: ModelConfig, h, k_pool, v_pool, attn,
-                      cross=None):
-    """Layer runner over the per-layer page pools — (L, n_pages, page,
-    kvh, hd) K/V for GQA, (L, n_pages, page, width) (latent, rope-key)
-    for MLA: prefix and suffix unrolled, body scanned — pool rows are
-    indexed by absolute layer id so the engines' PagePool layout is
-    position-stable.  CROSS_ATTN layers additionally run ``cross``
-    against the same layer slice (self and cross pages share the pool;
-    the tables are distinct)."""
-    npre = len(cfg.prefix)
-    pat = len(cfg.pattern)
-
-    def one(p_block, h, k_pool, v_pool, layer):
+def _one_layer(fn):
+    """Run ``fn(p, x, k_layer, v_layer)``, an attention over one
+    layer's pool (MLA's latent pages), as a whole-pool ``attn``: slice
+    layer ``layer`` out and write it back."""
+    def attn(p, x, k_pool, v_pool, layer):
         k_layer = jax.lax.dynamic_index_in_dim(k_pool, layer, 0,
                                                keepdims=False)
         v_layer = jax.lax.dynamic_index_in_dim(v_pool, layer, 0,
                                                keepdims=False)
-        h, k_layer, v_layer = _paged_attn_block(p_block, cfg, h, k_layer,
-                                                v_layer, attn, cross)
-        h = SH.act_constrain(h)
-        k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, k_layer,
-                                                     layer, 0)
-        v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, v_layer,
-                                                     layer, 0)
-        return h, k_pool, v_pool
+        a, k_layer, v_layer = fn(p, x, k_layer, v_layer)
+        return (a, jax.lax.dynamic_update_index_in_dim(k_pool, k_layer,
+                                                       layer, 0),
+                jax.lax.dynamic_update_index_in_dim(v_pool, v_layer,
+                                                    layer, 0))
+    return attn
+
+
+def _run_layers_paged(params, cfg: ModelConfig, h, k_pool, v_pool, attn,
+                      cross=None):
+    """Layer runner over the page pools — (L, n_pages, page, kvh * hd)
+    K/V for GQA, (L, n_pages, page, width) (latent, rope-key) for MLA:
+    prefix and suffix unrolled, body scanned.  Each block gets the whole
+    pools and its absolute layer id, so the engines' PagePool layout is
+    position-stable and the GQA kernels read and write the pool in
+    place.  CROSS_ATTN layers additionally run ``cross`` against the
+    same layer (self and cross pages share the pool; the tables are
+    distinct)."""
+    npre = len(cfg.prefix)
+    pat = len(cfg.pattern)
+
+    def one(p_block, h, k_pool, v_pool, layer):
+        h, k_pool, v_pool = _paged_attn_block(p_block, cfg, h, k_pool,
+                                              v_pool, layer, attn, cross)
+        return SH.act_constrain(h), k_pool, v_pool
 
     h = SH.act_constrain(h)
     for i in range(npre):
@@ -473,7 +483,7 @@ def prefill_paged(params, cfg: ModelConfig, tokens, q_offset, kv_len,
     last_idx: (segs,) index of each segment's last valid token;
     block_tables: (segs, n_slots) physical page ids (pad slots -> scratch
     page); pages_idx/offs_idx: (segs, sq) physical slot per token;
-    k_pool/v_pool: (L, n_pages, page, kvh, hd).
+    k_pool/v_pool: (L, n_pages, page, kvh * hd).
 
     Cross-attention archs (VLM / enc-dec) thread a SECOND block table:
     enc_embeds: (segs, enc_ctx, d) frontend embeddings (run through the
@@ -489,32 +499,33 @@ def prefill_paged(params, cfg: ModelConfig, tokens, q_offset, kv_len,
     sq = tokens.shape[1]
     positions = q_offset[:, None] + jnp.arange(sq)[None, :]
     h = _embed(params, cfg, tokens, positions)
-    attn_fn = (A.mla_prefill_paged if cfg.mla is not None
-               else A.gqa_prefill_paged)
-
-    def attn(p, x, k_layer, v_layer):
-        return attn_fn(
-            p, cfg, x, k_layer, v_layer, positions=positions,
-            q_offset=q_offset, kv_len=kv_len, block_tables=block_tables,
-            pages_idx=pages_idx, offs_idx=offs_idx,
-            window=cfg.sliding_window)
+    kw = dict(positions=positions, q_offset=q_offset, kv_len=kv_len,
+              block_tables=block_tables, pages_idx=pages_idx,
+              offs_idx=offs_idx, window=cfg.sliding_window)
+    if cfg.mla is not None:
+        attn = _one_layer(lambda p, x, k, v: A.mla_prefill_paged(
+            p, cfg, x, k, v, **kw))
+    else:
+        def attn(p, x, k_pool, v_pool, layer):
+            return A.gqa_prefill_paged(p, cfg, x, k_pool, v_pool, layer,
+                                       **kw)
 
     cross = None
     if enc_embeds is not None:
         enc_h = (encoder_forward(params, cfg, enc_embeds)
                  if cfg.is_encoder_decoder else enc_embeds)
 
-        def cross(p, x, k_layer, v_layer):
+        def cross(p, x, k_pool, v_pool, layer):
             return A.cross_prefill_paged(
-                p, cfg, x, k_layer, v_layer, enc_h=enc_h,
+                p, cfg, x, k_pool, v_pool, layer, enc_h=enc_h,
                 cross_bt=cross_bt, cross_len=cross_len,
                 cross_pg=cross_pg, cross_off=cross_off)
     elif cross_bt is not None:
         # read-only cross chunk: every segment's cross pages are already
         # written (first chunk ran earlier, or the pages came from the
         # cross cache) — skip the O(enc_ctx²) encoder stack + scatter
-        def cross(p, x, k_layer, v_layer):
-            return A.cross_attend_paged(p, cfg, x, k_layer, v_layer,
+        def cross(p, x, k_pool, v_pool, layer):
+            return A.cross_attend_paged(p, cfg, x, k_pool, v_pool, layer,
                                         cross_bt=cross_bt,
                                         cross_len=cross_len)
 
@@ -572,19 +583,20 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, pos, pages, offs,
     (next_tokens (slots,) int32, k_pool, v_pool).
     """
     h = _embed(params, cfg, tokens, pos[:, None])
-    attn_fn = (A.mla_decode_paged if cfg.mla is not None
-               else A.gqa_decode_paged)
-
-    def attn(p, x, k_layer, v_layer):
-        return attn_fn(
-            p, cfg, x, k_layer, v_layer, pos=pos, pages=pages, offs=offs,
-            block_tables=block_tables, lens=lens,
-            window=cfg.sliding_window)
+    kw = dict(pos=pos, pages=pages, offs=offs, block_tables=block_tables,
+              lens=lens, window=cfg.sliding_window)
+    if cfg.mla is not None:
+        attn = _one_layer(lambda p, x, k, v: A.mla_decode_paged(
+            p, cfg, x, k, v, **kw))
+    else:
+        def attn(p, x, k_pool, v_pool, layer):
+            return A.gqa_decode_paged(p, cfg, x, k_pool, v_pool, layer,
+                                      **kw)
 
     cross = None
     if cross_bt is not None:
-        def cross(p, x, k_layer, v_layer):
-            return A.cross_decode_paged(p, cfg, x, k_layer, v_layer,
+        def cross(p, x, k_pool, v_pool, layer):
+            return A.cross_decode_paged(p, cfg, x, k_pool, v_pool, layer,
                                         cross_bt=cross_bt,
                                         cross_len=cross_len)
 

@@ -13,6 +13,11 @@ def _mk(shape, dtype, k):
                              ).astype(dtype)
 
 
+def _pool(npages, page, kvh, hd, dtype, k, layers=1):
+    """A lane-dense (layers, npages, page, kvh * hd) page pool."""
+    return _mk((layers, npages, page, kvh * hd), dtype, k)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,sq,h,kvh,hd,skv,bq,bk", [
     (1, 64, 4, 4, 64, 256, 32, 64),      # MHA
@@ -71,19 +76,21 @@ def test_chunked_prefill_mid_prompt_chunk():
     (2, 4, 2, 64, 16, 64, 6),
     (1, 8, 8, 32, 8, 16, 8),      # MHA, small pages
     (4, 4, 1, 128, 32, 64, 4),    # MQA
+    (3, 14, 2, 64, 12, 16, 5),    # qwen2 heads: two kv heads per lane tile
+    (2, 8, 4, 64, 10, 16, 4),     # two lane blocks of two kv heads
 ])
 def test_paged_decode_attention_sweep(dtype, b, h, kvh, hd, npages, page,
                                       nslots):
     q = _mk((b, h, hd), dtype, 10)
-    kp = _mk((npages, page, kvh, hd), dtype, 11)
-    vp = _mk((npages, page, kvh, hd), dtype, 12)
+    kp = _pool(npages, page, kvh, hd, dtype, 11)
+    vp = _pool(npages, page, kvh, hd, dtype, 12)
     bt = jax.random.randint(jax.random.fold_in(KEY, 13), (b, nslots), 0,
                             npages)
     maxlen = nslots * page
     lens = jax.random.randint(jax.random.fold_in(KEY, 14), (b,), 1,
                               maxlen + 1)
-    out = ops.decode_attention(q, kp, vp, bt, lens)
-    exp = ref.ref_paged_decode_attention(q, kp, vp, bt, lens)
+    out = ops.decode_attention(q, kp, vp, 0, bt, lens)
+    exp = ref.ref_paged_decode_attention(q, kp, vp, 0, bt, lens)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     assert out.shape == exp.shape
     assert float(jnp.abs(out.astype(jnp.float32)
@@ -95,14 +102,16 @@ def test_paged_decode_attention_sweep(dtype, b, h, kvh, hd, npages, page,
     (3, 32, 4, 2, 64, 10, 16, 4, 16),    # GQA, ragged offsets
     (1, 16, 4, 4, 32, 6, 16, 3, 16),     # MHA
     (2, 64, 8, 1, 64, 12, 32, 4, 32),    # MQA, bigger pages
+    (2, 32, 14, 2, 64, 10, 16, 4, 16),   # qwen2 heads
+    (2, 16, 8, 4, 64, 10, 16, 3, 16),    # two lane blocks
 ])
 def test_paged_prefill_attention_sweep(dtype, b, sq, h, kvh, hd, npages,
                                        page, nslots, bq):
     """The fused-chunk serving kernel: per-segment q_offset/kv_len over a
     block-table-addressed page pool."""
     q = _mk((b, sq, h, hd), dtype, 21)
-    kp = _mk((npages, page, kvh, hd), dtype, 22)
-    vp = _mk((npages, page, kvh, hd), dtype, 23)
+    kp = _pool(npages, page, kvh, hd, dtype, 22)
+    vp = _pool(npages, page, kvh, hd, dtype, 23)
     bt = jax.random.randint(jax.random.fold_in(KEY, 24), (b, nslots), 0,
                             npages)
     maxlen = nslots * page
@@ -110,8 +119,8 @@ def test_paged_prefill_attention_sweep(dtype, b, sq, h, kvh, hd, npages,
                                maxlen - sq + 1)
     kv_len = jnp.minimum(q_off + sq, maxlen)
     out = ops.prefill_attention(q, kp, vp, kv_len, q_off, block_table=bt,
-                                block_q=bq)
-    exp = ref.ref_paged_prefill_attention(q, kp, vp, bt, kv_len, q_off)
+                                layer=0, block_q=bq)
+    exp = ref.ref_paged_prefill_attention(q, kp, vp, 0, bt, kv_len, q_off)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     assert out.shape == exp.shape
     assert not bool(jnp.isnan(out.astype(jnp.float32)).any())
@@ -128,13 +137,13 @@ def test_paged_prefill_matches_dense_prefill_kernel():
     k = _mk((b, skv, kvh, hd), jnp.float32, 27)
     v = _mk((b, skv, kvh, hd), jnp.float32, 28)
     # lay the dense caches out in a pool: request i -> pages [4i, 4i+4)
-    kp = k.reshape(b * nslots, page, kvh, hd)
-    vp = v.reshape(b * nslots, page, kvh, hd)
+    kp = k.reshape(1, b * nslots, page, kvh * hd)
+    vp = v.reshape(1, b * nslots, page, kvh * hd)
     bt = jnp.arange(b * nslots, dtype=jnp.int32).reshape(b, nslots)
     q_off = jnp.array([skv - sq, 11], jnp.int32)
     kv_len = q_off + sq
     out_paged = ops.prefill_attention(q, kp, vp, kv_len, q_off,
-                                      block_table=bt, block_q=16)
+                                      block_table=bt, layer=0, block_q=16)
     # dense kernel takes a single shared q_offset -> compare per request
     for i in range(b):
         out_dense = ops.prefill_attention(
@@ -150,15 +159,15 @@ def test_paged_prefill_attention_window(window):
     agree with the dense-gather oracle across page-boundary cases."""
     b, sq, h, kvh, hd, npages, page, nslots = 3, 16, 4, 2, 32, 12, 16, 4
     q = _mk((b, sq, h, hd), jnp.float32, 30)
-    kp = _mk((npages, page, kvh, hd), jnp.float32, 31)
-    vp = _mk((npages, page, kvh, hd), jnp.float32, 32)
+    kp = _pool(npages, page, kvh, hd, jnp.float32, 31)
+    vp = _pool(npages, page, kvh, hd, jnp.float32, 32)
     bt = jax.random.randint(jax.random.fold_in(KEY, 33), (b, nslots), 0,
                             npages)
     q_off = jnp.array([0, 17, 48], jnp.int32)   # incl. offset mid-page
     kv_len = q_off + sq
     out = ops.prefill_attention(q, kp, vp, kv_len, q_off, block_table=bt,
-                                window=window, block_q=16)
-    exp = ref.ref_paged_prefill_attention(q, kp, vp, bt, kv_len, q_off,
+                                layer=0, window=window, block_q=16)
+    exp = ref.ref_paged_prefill_attention(q, kp, vp, 0, bt, kv_len, q_off,
                                           window=window)
     assert not bool(jnp.isnan(out).any())
     assert float(jnp.abs(out - exp).max()) < 2e-5
@@ -171,14 +180,14 @@ def test_paged_decode_attention_window(window):
     matches the oracle at page boundaries."""
     b, h, kvh, hd, npages, page, nslots = 4, 4, 2, 32, 12, 16, 4
     q = _mk((b, h, hd), jnp.float32, 34)
-    kp = _mk((npages, page, kvh, hd), jnp.float32, 35)
-    vp = _mk((npages, page, kvh, hd), jnp.float32, 36)
+    kp = _pool(npages, page, kvh, hd, jnp.float32, 35)
+    vp = _pool(npages, page, kvh, hd, jnp.float32, 36)
     bt = jax.random.randint(jax.random.fold_in(KEY, 37), (b, nslots), 0,
                             npages)
     # lens straddling page boundaries: window end mid-page / on-page-edge
     lens = jnp.array([5, 16, 33, 64], jnp.int32)
-    out = ops.decode_attention(q, kp, vp, bt, lens, window=window)
-    exp = ref.ref_paged_decode_attention(q, kp, vp, bt, lens,
+    out = ops.decode_attention(q, kp, vp, 0, bt, lens, window=window)
+    exp = ref.ref_paged_decode_attention(q, kp, vp, 0, bt, lens,
                                          window=window)
     assert float(jnp.abs(out - exp).max()) < 2e-5
 
@@ -188,15 +197,16 @@ def test_paged_decode_window_ignores_slid_out_pages():
     the kernel must never let that page reach the softmax."""
     b, h, kvh, hd, npages, page = 1, 4, 2, 32, 4, 8
     q = _mk((b, h, hd), jnp.float32, 38)
-    kp = _mk((npages, page, kvh, hd), jnp.float32, 39)
-    vp = _mk((npages, page, kvh, hd), jnp.float32, 40)
+    kp = _pool(npages, page, kvh, hd, jnp.float32, 39)
+    vp = _pool(npages, page, kvh, hd, jnp.float32, 40)
     # request: 24 tokens over slots [0,1,2]; window 8 -> the query at
     # position 23 attends keys 16..23, so slots 0 AND 1 are dead
     bt_live = jnp.array([[0, 1, 2]], jnp.int32)
     bt_trash = jnp.array([[3, 3, 2]], jnp.int32)   # dead slots -> scratch
     lens = jnp.array([24], jnp.int32)
-    out_live = ops.decode_attention(q, kp, vp, bt_live, lens, window=8)
-    out_trash = ops.decode_attention(q, kp, vp, bt_trash, lens, window=8)
+    out_live = ops.decode_attention(q, kp, vp, 0, bt_live, lens, window=8)
+    out_trash = ops.decode_attention(q, kp, vp, 0, bt_trash, lens,
+                                     window=8)
     assert float(jnp.abs(out_live - out_trash).max()) == 0.0
 
 
@@ -228,17 +238,76 @@ def test_paged_mla_decode_attention_sweep(dtype, window):
 def test_paged_decode_single_token_cache():
     """lens=1: only the first token of the first page is live."""
     q = _mk((1, 4, 64), jnp.float32, 15)
-    kp = _mk((4, 16, 2, 64), jnp.float32, 16)
-    vp = _mk((4, 16, 2, 64), jnp.float32, 17)
+    kp = _pool(4, 16, 2, 64, jnp.float32, 16)
+    vp = _pool(4, 16, 2, 64, jnp.float32, 17)
     bt = jnp.array([[2, 0]], jnp.int32)
     lens = jnp.array([1], jnp.int32)
-    out = ops.decode_attention(q, kp, vp, bt, lens)
-    exp = ref.ref_paged_decode_attention(q, kp, vp, bt, lens)
+    out = ops.decode_attention(q, kp, vp, 0, bt, lens)
+    exp = ref.ref_paged_decode_attention(q, kp, vp, 0, bt, lens)
     assert float(jnp.abs(out - exp).max()) < 1e-5
     # attention over one token == that token's V
-    v0 = vp[2, 0]  # (kvh, hd)
+    v0 = vp[0, 2, 0].reshape(2, 64)  # (kvh, hd)
     expand = jnp.repeat(v0, 2, axis=0)
     assert float(jnp.abs(out[0] - expand).max()) < 1e-5
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill", "cross"])
+def test_paged_kernels_read_their_layer(kernel):
+    """A kernel handed a multi-layer pool reads layer ``layer`` and no
+    other: the same call on layer 2 of a 4-layer pool whose other layers
+    hold other data equals the call on that layer alone, and differs
+    from the call on layer 0."""
+    b, sq, h, kvh, hd, npages, page, nslots = 2, 16, 14, 2, 64, 10, 16, 3
+    kp = _pool(npages, page, kvh, hd, jnp.float32, 50, layers=4)
+    vp = _pool(npages, page, kvh, hd, jnp.float32, 51, layers=4)
+    bt = jax.random.randint(jax.random.fold_in(KEY, 52), (b, nslots), 0,
+                            npages)
+    lens = jnp.array([37, 48], jnp.int32)
+    if kernel == "prefill":
+        q = _mk((b, sq, h, hd), jnp.float32, 53)
+        q_off = lens - sq
+
+        def call(k, v, layer):
+            return ops.prefill_attention(q, k, v, lens, q_off,
+                                         block_table=bt, layer=layer)
+        exp = ref.ref_paged_prefill_attention(q, kp, vp, 2, bt, lens, q_off)
+    else:
+        q = _mk((b, h, hd), jnp.float32, 53)
+        fn = (ops.decode_attention if kernel == "decode"
+              else ops.cross_decode_attention)
+
+        def call(k, v, layer):
+            return fn(q, k, v, layer, bt, lens)
+        exp = ref.ref_paged_decode_attention(q, kp, vp, 2, bt, lens)
+    out = call(kp, vp, jnp.int32(2))
+    alone = call(kp[2:3], vp[2:3], 0)
+    assert float(jnp.abs(out - exp).max()) < 2e-5
+    assert float(jnp.abs(out - alone).max()) == 0.0
+    assert float(jnp.abs(out - call(kp, vp, 0)).max()) > 1e-2
+
+
+@pytest.mark.parametrize("kvh,hd,g", [
+    (2, 64, 2),      # qwen2: two kv heads per 128-lane tile
+    (8, 128, 1),     # a head per tile: queries pass as they are
+    (3, 32, 3),      # row under a tile: one block of every kv head
+])
+def test_spread_and_gather_heads(kvh, hd, g):
+    """A spread head holds its values on its own kv head's lanes of the
+    lane block and zeros elsewhere; gathering a spread tensor gives it
+    back."""
+    from repro.kernels.pool_lanes import (gather_heads, heads_per_block,
+                                          spread_heads)
+    h = 2 * kvh
+    assert heads_per_block(kvh, hd) == g
+    q = _mk((3, h, hd), jnp.float32, 60)
+    qs = spread_heads(q, kvh, g)
+    assert qs.shape == (3, h, g * hd)
+    for i in range(h):
+        lane = (i // 2) % g
+        blk = qs[:, i].reshape(3, g, hd)
+        assert float(jnp.abs(blk[:, lane] - q[:, i]).max()) == 0.0
+        assert float(jnp.abs(blk.sum(1) - q[:, i]).max()) == 0.0
+    assert float(jnp.abs(gather_heads(qs, kvh, g) - q).max()) == 0.0
 
 
 def test_kernel_matches_model_flash_attention():

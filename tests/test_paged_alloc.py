@@ -149,6 +149,7 @@ def test_page_pool_roundtrip():
     import numpy as np
     pool = PagePool.create(n_layers=2, n_pages=8, page_size=4, kvh=2, hd=8,
                            dtype=jnp.float32)
+    assert pool.k.shape == pool.v.shape == (2, 8, 4, 2 * 8)   # lane-dense
     k = jnp.arange(8 * 2 * 8, dtype=jnp.float32).reshape(8, 2, 8)
     pool = pool.write_chunk(1, np.array([3, 5]), k, k * 2)
     kl, vl = pool.layer(1)
@@ -156,4 +157,4 @@ def test_page_pool_roundtrip():
     assert float(abs(vl[5].reshape(-1) - 2 * k[4:].reshape(-1)).max()) == 0
     pool = pool.write_token(0, 2, 1, k[0], k[1])
     kl0, vl0 = pool.layer(0)
-    assert float(abs(kl0[2, 1] - k[0]).max()) == 0
+    assert float(abs(kl0[2, 1] - k[0].reshape(-1)).max()) == 0

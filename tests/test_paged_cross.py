@@ -64,13 +64,13 @@ def test_cross_decode_kernel_sweep(dtype, enc_lens):
     page-boundary encoder lengths (non-causal, no window)."""
     b, h, kvh, hd, npages, page, nslots = 2, 4, 2, 32, 12, 16, 4
     q = _mk((b, h, hd), 1).astype(dtype)
-    kp = _mk((npages, page, kvh, hd), 2).astype(dtype)
-    vp = _mk((npages, page, kvh, hd), 3).astype(dtype)
+    kp = _mk((1, npages, page, kvh * hd), 2).astype(dtype)
+    vp = _mk((1, npages, page, kvh * hd), 3).astype(dtype)
     bt = jax.random.randint(jax.random.fold_in(KEY, 4), (b, nslots), 0,
                             npages)
     lens = jnp.asarray(enc_lens, jnp.int32)
-    out = ops.cross_decode_attention(q, kp, vp, bt, lens)
-    exp = ref.ref_paged_cross_decode_attention(q, kp, vp, bt, lens)
+    out = ops.cross_decode_attention(q, kp, vp, 0, bt, lens)
+    exp = ref.ref_paged_cross_decode_attention(q, kp, vp, 0, bt, lens)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     assert out.shape == exp.shape
     assert not bool(jnp.isnan(out.astype(jnp.float32)).any())
@@ -83,12 +83,12 @@ def test_cross_decode_kernel_ignores_pad_slots():
     scratch page — they must never reach the softmax."""
     b, h, kvh, hd, npages, page = 1, 4, 2, 32, 4, 8
     q = _mk((b, h, hd), 5)
-    kp = _mk((npages, page, kvh, hd), 6)
-    vp = _mk((npages, page, kvh, hd), 7)
+    kp = _mk((1, npages, page, kvh * hd), 6)
+    vp = _mk((1, npages, page, kvh * hd), 7)
     lens = jnp.asarray([8], jnp.int32)           # exactly one page valid
-    out_a = ops.cross_decode_attention(q, kp, vp,
+    out_a = ops.cross_decode_attention(q, kp, vp, 0,
                                        jnp.asarray([[0, 1, 2]]), lens)
-    out_b = ops.cross_decode_attention(q, kp, vp,
+    out_b = ops.cross_decode_attention(q, kp, vp, 0,
                                        jnp.asarray([[0, 3, 3]]), lens)
     assert float(jnp.abs(out_a - out_b).max()) == 0.0
 
@@ -101,15 +101,15 @@ def test_cross_prefill_noncausal_kernel(enc_len):
     page-boundary encoder lengths."""
     b, sq, h, kvh, hd, npages, page, nslots = 2, 16, 4, 2, 32, 12, 16, 3
     q = _mk((b, sq, h, hd), 8)
-    kp = _mk((npages, page, kvh, hd), 9)
-    vp = _mk((npages, page, kvh, hd), 10)
+    kp = _mk((1, npages, page, kvh * hd), 9)
+    vp = _mk((1, npages, page, kvh * hd), 10)
     bt = jax.random.randint(jax.random.fold_in(KEY, 11), (b, nslots), 0,
                             npages)
     lens = jnp.asarray([enc_len, max(1, enc_len - 2)], jnp.int32)
     zero = jnp.zeros_like(lens)
     out = ops.prefill_attention(q, kp, vp, lens, zero, block_table=bt,
-                                causal=False)
-    exp = ref.ref_paged_prefill_attention(q, kp, vp, bt, lens, zero,
+                                layer=0, causal=False)
+    exp = ref.ref_paged_prefill_attention(q, kp, vp, 0, bt, lens, zero,
                                           causal=False)
     assert not bool(jnp.isnan(out).any())
     assert float(jnp.abs(out - exp).max()) < 2e-5

@@ -65,7 +65,7 @@ def test_fused_chunk_prefill_matches_per_segment_dense(setup):
         pkd = out_d[rid]
         assert pkp.first_token == pkd.first_token
         plen = pkp.req.prompt_len
-        # paged payload: (L, n_pages, page, kvh, hd) -> (L, plen, kvh, hd)
+        # paged payload: (L, n_pages, page, kvh * hd) -> (L, plen, kvh, hd)
         kp = np.asarray(pkp.pages_k).reshape(
             cfg.n_layers, -1, cfg.n_kv_heads, cfg.resolved_head_dim)
         vp = np.asarray(pkp.pages_v).reshape(
@@ -415,8 +415,9 @@ def test_pool_gather_install_roundtrip():
     """PagePool.gather on one pool == the transfer payload a second pool
     installs — the page-granular KV handoff is lossless."""
     pool_a = PagePool.create(2, 8, PAGE, 2, 16, jnp.float32)
+    assert pool_a.k.shape == (2, 8, PAGE, 2 * 16)
     k = jnp.arange(2 * 3 * PAGE * 2 * 16, dtype=jnp.float32).reshape(
-        2, 3, PAGE, 2, 16)
+        2, 3, PAGE, 2 * 16)
     pool_a = PagePool(k=pool_a.k.at[:, jnp.asarray([1, 4, 6])].set(k),
                       v=pool_a.v.at[:, jnp.asarray([1, 4, 6])].set(2 * k))
     pk, pv = pool_a.gather([1, 4, 6])

@@ -291,7 +291,7 @@ def test_pool_copy_pages_replays_cow_bytes():
     import jax.numpy as jnp
     pool = PagePool.create(2, 6, PAGE, 2, 8, jnp.float32)
     k = jnp.arange(2 * 1 * PAGE * 2 * 8, dtype=jnp.float32).reshape(
-        2, 1, PAGE, 2, 8)
+        2, 1, PAGE, 2 * 8)
     pool = PagePool(k=pool.k.at[:, jnp.asarray([1])].set(k),
                     v=pool.v.at[:, jnp.asarray([1])].set(3 * k))
     pool = pool.copy_pages([1], [4])

@@ -8,6 +8,7 @@ topology is described inside a fixture, never at import time, and the
 persistent compilation cache is off around the compiles.
 """
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,16 +69,33 @@ def _gqa(arch):
     return cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
 
 
+def _pool(sds, kvh, hd, layers=1):
+    """A lane-dense (layers, N_PAGES, PAGE, kvh * hd) bf16 pool."""
+    return sds((layers, N_PAGES, PAGE, kvh * hd), BF16)
+
+
+# an HLO instruction: ``%name = type[dims]{layout} opcode(``
+_HLO_OP = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+([\w-]+)\(")
+
+
+def _pool_moves(hlo: str):
+    """Copies, slices and slice updates whose result carries the pool's
+    page dimension: a relayout or a layer cut of the pool."""
+    return [m.group(0) for m in _HLO_OP.finditer(hlo)
+            if m.group(2) in ("copy", "dynamic-slice", "dynamic-update-slice")
+            and str(N_PAGES) in m.group(1).split(",")]
+
+
 @pytest.mark.parametrize("block_q", [16, 128])
 @pytest.mark.parametrize("arch", ["qwen2_0_5b", "mistral_nemo_12b"])
 def test_paged_prefill_compiles(sds, arch, block_q):
     h, kvh, hd = _gqa(arch)
     segs, sq = 4, 512
     c = _compile(
-        lambda q, k, v, bt, kl, qo: paged_prefill_attention(
-            q, k, v, bt, kl, qo, block_q=block_q, interpret=False),
-        sds((segs, sq, h, hd), BF16), sds((N_PAGES, PAGE, kvh, hd), BF16),
-        sds((N_PAGES, PAGE, kvh, hd), BF16), sds((segs, SLOTS), I32),
+        lambda q, k, v, ly, bt, kl, qo: paged_prefill_attention(
+            q, k, v, ly, bt, kl, qo, block_q=block_q, interpret=False),
+        sds((segs, sq, h, hd), BF16), _pool(sds, kvh, hd, 2),
+        _pool(sds, kvh, hd, 2), sds((), I32), sds((segs, SLOTS), I32),
         sds((segs,), I32), sds((segs,), I32))
     assert "tpu_custom_call" in c.as_text()
 
@@ -87,10 +105,10 @@ def test_paged_decode_compiles(sds, arch):
     h, kvh, hd = _gqa(arch)
     b = 16
     c = _compile(
-        lambda q, k, v, bt, ln: paged_decode_attention(
-            q, k, v, bt, ln, interpret=False),
-        sds((b, h, hd), BF16), sds((N_PAGES, PAGE, kvh, hd), BF16),
-        sds((N_PAGES, PAGE, kvh, hd), BF16), sds((b, SLOTS), I32),
+        lambda q, k, v, ly, bt, ln: paged_decode_attention(
+            q, k, v, ly, bt, ln, interpret=False),
+        sds((b, h, hd), BF16), _pool(sds, kvh, hd, 2),
+        _pool(sds, kvh, hd, 2), sds((), I32), sds((b, SLOTS), I32),
         sds((b,), I32))
     assert "tpu_custom_call" in c.as_text()
 
@@ -116,25 +134,28 @@ def test_paged_cross_decode_compiles(sds):
     h, kvh, hd = _gqa("whisper_tiny")
     b, cross_slots = 16, -(-cfg.cross_ctx // PAGE)
     c = _compile(
-        lambda q, k, v, bt, ln: paged_cross_decode_attention(
-            q, k, v, bt, ln, interpret=False),
-        sds((b, h, hd), BF16), sds((N_PAGES, PAGE, kvh, hd), BF16),
-        sds((N_PAGES, PAGE, kvh, hd), BF16), sds((b, cross_slots), I32),
+        lambda q, k, v, ly, bt, ln: paged_cross_decode_attention(
+            q, k, v, ly, bt, ln, interpret=False),
+        sds((b, h, hd), BF16), _pool(sds, kvh, hd, 2),
+        _pool(sds, kvh, hd, 2), sds((), I32), sds((b, cross_slots), I32),
         sds((b,), I32))
     assert "tpu_custom_call" in c.as_text()
 
 
 @pytest.mark.parametrize("step", ["prefill", "decode"])
 def test_qwen2_serving_step_compiles(sds, monkeypatch, step):
-    """The engines' whole 24-layer paged steps: a Mosaic call inside, and
-    the donated pools aliased — no copy of the pool per step."""
+    """The engines' whole 24-layer paged steps: a Mosaic call inside,
+    the donated pools aliased, and no copy, layer slice or layer update
+    of the pool anywhere in the program — the kernels read it and the
+    scatters write it in place."""
     # ops picks interpret mode from the backend it sees (the CPU here)
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     cfg = get_config("qwen2_0_5b")
     params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
                                     M.abstract_params(cfg))
-    pool = sds((cfg.n_layers, N_PAGES, PAGE, cfg.n_kv_heads,
-                cfg.resolved_head_dim), jnp.dtype(cfg.dtype))
+    pool = sds((cfg.n_layers, N_PAGES, PAGE,
+                cfg.n_kv_heads * cfg.resolved_head_dim),
+               jnp.dtype(cfg.dtype))
     if step == "prefill":
         ns, sq = 2, 256
         c = jax.jit(
@@ -153,7 +174,9 @@ def test_qwen2_serving_step_compiles(sds, monkeypatch, step):
             params, sds((b, 1), I32), sds((b,), I32), sds((b,), I32),
             sds((b,), I32), sds((b, SLOTS), I32), sds((b,), I32), pool,
             pool).compile()
-    assert "tpu_custom_call" in c.as_text()
+    hlo = c.as_text()
+    assert "tpu_custom_call" in hlo
+    assert _pool_moves(hlo) == []
     mem = c.memory_analysis()
     pool_bytes = 2 * math.prod(pool.shape) * pool.dtype.itemsize
     assert mem.alias_size_in_bytes >= pool_bytes
